@@ -2,8 +2,14 @@
 from __future__ import annotations
 
 import argparse
+import importlib
 import json
+import os
+import subprocess
 import sys
+import tempfile
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _gate(gates: list, name: str, actual, threshold, passed: bool) -> None:
@@ -282,6 +288,45 @@ def _checks(all_rows, crashed=()) -> bool:
     return not failed
 
 
+#: (module, label) of every suite, in run order; ``--check`` runs the
+#: BENCH-gated subset
+SUITE = [
+    ("linked_list", "fig4_linked_list"),
+    ("hash_table", "fig5_fig6_hash_table"),
+    ("memory_release", "fig3_memory_release"),
+    ("memory_release_device", "fig3_device_memory_release"),
+    ("reclaim_matrix", "reclaim_policy_matrix"),
+    ("paged_attention_bench", "device_paged_attention"),
+    ("decode_throughput", "decode_throughput"),
+    ("prefix_cache", "prefix_cache_sharing"),
+    ("prefill_throughput", "chunked_prefill"),
+    ("speculative", "speculative_decoding"),
+    ("multi_pool", "data_parallel_multi_pool"),
+    ("tensor_parallel", "tensor_parallel_serving"),
+    ("chaos_goodput", "chaos_goodput_self_healing"),
+    ("traffic", "traffic_tail_latency"),
+]
+CHECK_SUITE = [m for m in SUITE if m[0] not in (
+    "linked_list", "hash_table", "memory_release", "paged_attention_bench")]
+
+
+def _run_suite(module: str, quick: bool) -> list | None:
+    """Run one suite in a child process of its own and return its rows
+    (None if it raised).  This parent never touches JAX, so a suite — or a
+    child that suite starts — can take the accelerator: a process that has
+    initialised a JAX backend holds the chip until it exits."""
+    with tempfile.TemporaryDirectory() as d:
+        out = os.path.join(d, "rows.json")
+        res = subprocess.run(
+            [sys.executable, "-m", "benchmarks.run", "--suite", module,
+             "--emit", out] + ([] if quick else ["--paper-scale"]),
+            cwd=ROOT)
+        if res.returncode != 0:
+            return None
+        with open(out) as f:
+            return json.load(f)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--paper-scale", action="store_true",
@@ -289,54 +334,28 @@ def main() -> None:
     ap.add_argument("--check", action="store_true",
                     help="CI gate: run only the BENCH_*.json emitters (quick "
                          "mode) and validate their thresholds")
+    ap.add_argument("--suite", help=argparse.SUPPRESS)
+    ap.add_argument("--emit", help=argparse.SUPPRESS)
     args = ap.parse_args()
     quick = not args.paper_scale
 
-    from . import (chaos_goodput, decode_throughput, hash_table, linked_list,
-                   memory_release, memory_release_device, multi_pool,
-                   paged_attention_bench, prefix_cache, prefill_throughput,
-                   reclaim_matrix, speculative, tensor_parallel, traffic)
-
-    suite = [
-        (linked_list, "fig4_linked_list"),
-        (hash_table, "fig5_fig6_hash_table"),
-        (memory_release, "fig3_memory_release"),
-        (memory_release_device, "fig3_device_memory_release"),
-        (reclaim_matrix, "reclaim_policy_matrix"),
-        (paged_attention_bench, "device_paged_attention"),
-        (decode_throughput, "decode_throughput"),
-        (prefix_cache, "prefix_cache_sharing"),
-        (prefill_throughput, "chunked_prefill"),
-        (speculative, "speculative_decoding"),
-        (multi_pool, "data_parallel_multi_pool"),
-        (tensor_parallel, "tensor_parallel_serving"),
-        (chaos_goodput, "chaos_goodput_self_healing"),
-        (traffic, "traffic_tail_latency"),
-    ]
-    if args.check:  # the BENCH-gated subset only
-        suite = [
-            (memory_release_device, "fig3_device_memory_release"),
-            (reclaim_matrix, "reclaim_policy_matrix"),
-            (decode_throughput, "decode_throughput"),
-            (prefix_cache, "prefix_cache_sharing"),
-            (prefill_throughput, "chunked_prefill"),
-            (speculative, "speculative_decoding"),
-            (multi_pool, "data_parallel_multi_pool"),
-            (tensor_parallel, "tensor_parallel_serving"),
-            (chaos_goodput, "chaos_goodput_self_healing"),
-            (traffic, "traffic_tail_latency"),
-        ]
+    if args.suite:  # child: one suite, rows to --emit
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
+        mod = importlib.import_module(f"benchmarks.{args.suite}")
+        rows = mod.run(quick=quick)
+        with open(args.emit, "w") as f:
+            json.dump(rows, f, default=float)
+        return
 
     all_rows = []
     crashed = []
-    for mod, label in suite:
+    for module, label in CHECK_SUITE if args.check else SUITE:
         print(f"# {label}", flush=True)
-        try:
-            rows = mod.run(quick=quick)
-        except Exception as exc:  # a crashing suite is a failed gate, not
-            # the end of the run — the others still report actual numbers
-            print(f"# {label} CRASHED: {type(exc).__name__}: {exc}",
-                  flush=True)
+        rows = _run_suite(module, quick)
+        if rows is None:  # a crashing suite is a failed gate, not the end
+            # of the run — the others still report actual numbers
+            print(f"# {label} CRASHED (traceback above)", flush=True)
             crashed.append(label)
             continue
         all_rows.extend(rows)

@@ -292,7 +292,6 @@ def run(quick: bool = True):
     if _DEVICE_FLAG.split("=")[0] not in env.get("XLA_FLAGS", ""):
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                             + " " + _DEVICE_FLAG).strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
     env["PYTHONPATH"] = (str(BENCH_PATH.parent / "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
     subprocess.run(

@@ -279,7 +279,6 @@ def _replay_smoke() -> None:
     """Cheap CI step: trace synthesis is deterministic, the JSONL
     round-trip is byte-exact, and a short replay drives a real engine
     (no timed gates — this is the correctness slice only)."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.path.insert(0, str(BENCH_PATH.parent / "src"))
     from repro.serving import dump_trace, load_trace, synthesize_trace
     kw = dict(duration_s=3.0, rate_rps=4.0, process="bursty",
@@ -304,11 +303,10 @@ def _replay_smoke() -> None:
 
 def run(quick: bool = True):
     """Benchmark entry point (benchmarks/run.py).  Re-runs itself in a
-    fresh subprocess so env (CPU platform, PYTHONPATH) is set before jax
-    loads — chaos_goodput convention."""
+    fresh subprocess so PYTHONPATH is set before jax loads — chaos_goodput
+    convention; the platform is the caller's (``JAX_PLATFORMS``)."""
     out = BENCH_PATH.parent / "BENCH_traffic_rows.tmp.json"
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     env["PYTHONPATH"] = (str(BENCH_PATH.parent / "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
     subprocess.run(

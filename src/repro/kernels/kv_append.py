@@ -44,7 +44,7 @@ def _kernel(pages_ref, slots_ref, k_new_ref, v_new_ref, k_ref, v_ref,
 
 @functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
 def kv_append_pallas(kv, block_tables, lengths, k_new, v_new, *,
-                     page_size: int, interpret: bool = True):
+                     page_size: int, interpret: bool):
     """kv {'k','v': [P, page, Hkv, D]}; block_tables [B, max_pages];
     lengths [B] (new token position); k_new/v_new [B, Hkv, D]."""
     B = lengths.shape[0]

@@ -3,8 +3,10 @@
 ``impl`` selection:
 - "pallas"    — pl.pallas_call compiled for TPU (the production path)
 - "interpret" — same kernel body executed in Python on CPU (correctness)
-- "ref"       — the pure-jnp oracle (fast on CPU; used by the serving engine
-                in this container)
+- "ref"       — the pure-jnp oracle (the serving path on CPU)
+
+:func:`resolve_attn_impl` is the one place the serving stack picks among
+them from the platform its devices report.
 """
 
 from __future__ import annotations
@@ -14,6 +16,27 @@ import jax.numpy as jnp
 
 from .paged_attention import paged_attention_pallas, paged_attention_sharded
 from .ref import paged_attention_chunked_ref, paged_attention_ref
+
+
+ATTN_IMPLS = ("pallas", "interpret", "ref")
+
+
+def resolve_attn_impl(impl: str | None, platform: str) -> str:
+    """The attention implementation for devices of ``platform``.
+
+    ``None`` follows the platform: the compiled Pallas kernel on a TPU, the
+    jnp oracle anywhere else.  An explicit choice is kept, except that
+    ``"interpret"`` on a TPU is refused — it would run the kernel body in
+    the Python interpreter on the host while the chip waits."""
+    if impl is None:
+        return "pallas" if platform == "tpu" else "ref"
+    if impl not in ATTN_IMPLS:
+        raise ValueError(f"unknown attention impl {impl!r}; expected one of "
+                         f"{ATTN_IMPLS} or None")
+    if impl == "interpret" and platform == "tpu":
+        raise ValueError("attn impl 'interpret' runs the Pallas kernel in the "
+                         "Python interpreter; on a TPU use 'pallas' (or None)")
+    return impl
 
 
 def paged_attention(q, kv, block_tables, lengths, *, impl: str = "ref",
@@ -31,7 +54,7 @@ def paged_attention(q, kv, block_tables, lengths, *, impl: str = "ref",
 
     ``pages_per_compute_block`` tiles the Pallas grid: each grid step fetches
     that many KV pages and runs one set of MXU dots over the combined
-    (ppcb*page_size, Hkv*D) tile (ignored by the jnp reference).
+    (ppcb*page_size, Hkv, D) tile (ignored by the jnp reference).
 
     ``mesh`` (tensor-parallel serving): the jnp reference needs nothing —
     GSPMD partitions it from the head-sharded arena layout — but a

@@ -21,10 +21,11 @@ TPU mapping:
   rides in scalar-prefetch memory (SMEM) so the ``index_map`` can translate
   virtual page slots to physical page ids *before* the DMAs are issued — the
   pagemap lookup of LRMalloc, done by the DMA engine.
-- Each grid step assembles a (ppcb*page_size, Hkv*D) KV tile from ``ppcb``
+- Each grid step assembles a (ppcb*page_size, Hkv, D) KV tile from ``ppcb``
   independently-mapped pages (one BlockSpec per page within the block — the
   pages are scattered in the arena, so each needs its own translation), then
-  issues ONE set of MXU dots for all C queries over the whole tile.  Larger
+  issues ONE batched MXU dot per kv head for all C queries over the whole
+  tile (bf16 operands, f32 accumulation).  Larger
   ``ppcb`` ⇒ fewer grid steps, fewer accumulator round-trips, larger dots —
   the same batching-of-validation amortization OA applies to reclamation.
 - **In-chunk causal mask**: ``lengths[b]`` is the row's TOTAL valid KV
@@ -43,8 +44,12 @@ TPU mapping:
 - Freed pages remain mapped in the persistent arena, so a stale block table
   entry fetches garbage *safely*; the scheduler's version check discards the
   result (OA semantics — reads validated after the fact).
-- Block shapes: page_size and Hkv*D should be multiples of (8, 128) for
-  MXU/VREG alignment; q is (C, Hkv*G, D) = (C, Hq, D).
+- Block shapes: the wrapper lays q out head-major, (Hkv, G*C, D) per row,
+  and hands the kernel each query row's causal limit as a (G*C, 1) column,
+  so every in-kernel array keeps (rows, lanes) in its last two dims and the
+  mask needs no vector reshape; D should be a multiple of 128.  At olmo-1b
+  widths (Hkv 16, D 128) the chunk form fits v5e's 16 MiB scoped VMEM at
+  every C the engine builds (``tests/test_tpu_compile.py``).
 
 Weak spots the sweep tests cover: GQA grouping, ragged lengths mid-page,
 unmapped (-1) table entries, page_size not dividing length, max_pages not
@@ -67,24 +72,23 @@ def _kernel(
     # scalar-prefetch
     block_tables_ref,  # [B, nblocks*ppcb] (SMEM)
     lengths_ref,  # [B] (SMEM) — total valid length incl. the chunk
-    chunk_lens_ref,  # [B] (SMEM) — live query slots (1..C)
-    # blocked inputs: q, then ppcb k-page refs, then ppcb v-page refs
-    q_ref,  # [1, C, Hq, D]
+    # blocked inputs: q, per-row causal limits, ppcb k-page refs, ppcb v-page
+    # refs
+    q_ref,  # [1, Hkv, R, D] — R = G*C query rows per kv head
+    limit_ref,  # [1, R, 1] int32 — each query row sees pos < limit
     *refs,
     page_size: int,
-    n_kv_heads: int,
     ppcb: int,
 ):
     k_refs = refs[:ppcb]  # each [1, page, Hkv, D]
     v_refs = refs[ppcb : 2 * ppcb]
-    o_ref = refs[2 * ppcb]  # [1, C, Hq, D]
-    m_ref, l_ref, acc_ref = refs[2 * ppcb + 1 :]  # VMEM scratch, C axis first
+    o_ref = refs[2 * ppcb]  # [1, Hkv, R, D]
+    m_ref, l_ref, acc_ref = refs[2 * ppcb + 1 :]  # VMEM scratch, head-major
 
     b = pl.program_id(0)
     i = pl.program_id(1)
     nb = pl.num_programs(1)
     span = ppcb * page_size
-    C = q_ref.shape[1]
 
     @pl.when(i == 0)
     def _init():
@@ -92,60 +96,55 @@ def _kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # per-page mapped bits from SMEM — drive both the skip predicate and the
-    # position mask (an unmapped page inside the block contributes nothing)
-    mapped = jnp.stack(
-        [block_tables_ref[b, i * ppcb + j] >= 0 for j in range(ppcb)]
-    )
+    # per-page mapped bits from SMEM as int32 SCALARS — they drive both the
+    # skip predicate and the position mask (an unmapped page inside the
+    # block contributes nothing).  Kept scalar on purpose: Mosaic cannot
+    # reshape a stacked bool vector into a mask.
+    mapped = [(block_tables_ref[b, i * ppcb + j] >= 0).astype(jnp.int32)
+              for j in range(ppcb)]
     start = i * span
-    block_live = (start < lengths_ref[b]) & jnp.any(mapped)
+    block_live = (start < lengths_ref[b]) & (functools.reduce(
+        jnp.maximum, mapped) > 0)
 
     @pl.when(block_live)
     def _compute():
-        q = q_ref[0]  # [C, Hq, D]
+        q = q_ref[0]  # [Hkv, R, D]
         k = jnp.concatenate([r[0] for r in k_refs], axis=0)  # [span, Hkv, D]
         v = jnp.concatenate([r[0] for r in v_refs], axis=0)
-        Hq, D = q.shape[1], q.shape[2]
-        G = Hq // n_kv_heads
-        qg = q.reshape(C, n_kv_heads, G, D).astype(jnp.float32)
-        # [C, Hkv, G, span] — one MXU dot per kv head for all C queries
-        s = jnp.einsum("chgd,phd->chgp", qg, k.astype(jnp.float32))
+        R, D = q.shape[1], q.shape[2]
+        # one batched MXU dot per kv head over all R query rows; operands
+        # stay in their storage dtype, accumulation is f32
+        s = jnp.einsum("hrd,phd->hrp", q, k,
+                       preferred_element_type=jnp.float32)
         s = s * (1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32)))
 
-        pos = start + jax.lax.iota(jnp.int32, span)
-        # in-chunk causal horizon: query j (global position
-        # lengths - chunk_lens + j) sees pos < that position + 1; padded
-        # slots (j >= chunk_lens) clamp to the full pos < lengths view
-        qpos = lengths_ref[b] - chunk_lens_ref[b] + jax.lax.iota(jnp.int32, C)
-        limit = jnp.minimum(qpos + 1, lengths_ref[b])
-        live = (pos[None, :] < limit[:, None]) & \
-            jnp.repeat(mapped, page_size)[None, :]  # [C, span]
-        s = jnp.where(live[:, None, None, :], s, -jnp.inf)
+        # [R, span] mask: the row's causal horizon (computed by the wrapper)
+        # AND the page of each position being mapped
+        t = jax.lax.broadcasted_iota(jnp.int32, (R, span), 1)
+        page_ok = jnp.zeros((R, span), jnp.int32)
+        for j in range(ppcb):
+            in_page = (t >= j * page_size) & (t < (j + 1) * page_size)
+            page_ok = jnp.where(in_page, mapped[j], page_ok)
+        live = ((start + t) < limit_ref[0]) & (page_ok > 0)
+        live = live[None]  # [1, R, span], broadcast over kv heads
+        s = jnp.where(live, s, -jnp.inf)
 
-        m_prev = m_ref[...].reshape(C, n_kv_heads, G)
-        l_prev = l_ref[...].reshape(C, n_kv_heads, G)
-        acc_prev = acc_ref[...].reshape(C, n_kv_heads, G, D)
-
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        m_prev = m_ref[...]  # [Hkv, R, 1]
+        l_prev = l_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.where(live[:, None, None, :],
-                      jnp.exp(s - m_safe[..., None]), 0.0)
+        p = jnp.where(live, jnp.exp(s - m_safe), 0.0)
         alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-        pv = jnp.einsum("chgp,phd->chgd", p, v.astype(jnp.float32))
-        acc_new = acc_prev * alpha[..., None] + pv
-
-        m_ref[...] = m_new.reshape(C, Hq)
-        l_ref[...] = l_new.reshape(C, Hq)
-        acc_ref[...] = acc_new.reshape(C, Hq, D)
+        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jnp.einsum("hrp,phd->hrd", p, v.astype(jnp.float32),
+                        preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = m_new
 
     @pl.when(i == nb - 1)
     def _finish():
-        Hq, D = o_ref.shape[2], o_ref.shape[3]
-        G = Hq // n_kv_heads
-        l = jnp.maximum(l_ref[...].reshape(C, n_kv_heads, G), 1e-30)
-        out = acc_ref[...].reshape(C, n_kv_heads, G, D) / l[..., None]
-        o_ref[0] = out.reshape(C, Hq, D).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -156,19 +155,23 @@ def _kernel(
 def paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths, *,
                            page_size: int, n_kv_heads: int,
                            pages_per_compute_block: int = 1,
-                           interpret: bool = True, chunk_lens=None):
+                           interpret: bool, chunk_lens=None):
     """q [B, Hq, D] (decode) or [B, C, Hq, D] (chunk) -> same shape back.
 
     ``lengths`` is the total valid KV length per row (including the chunk's
     appended tokens); ``chunk_lens`` [B] int32 gives each row's live query
     count for the in-chunk causal mask (None = every slot live — for the
-    decode form that is the classic single-query mask).  See the module
-    docstring for layout rules.
+    decode form that is the classic single-query mask).  ``interpret`` runs
+    the kernel body in the Pallas interpreter (CPU tests) instead of
+    compiling it with Mosaic.  See the module docstring for layout rules.
     """
     squeeze = q.ndim == 3
     if squeeze:
         q = q[:, None]
     B, C, Hq, D = q.shape
+    Hkv = n_kv_heads
+    G = Hq // Hkv
+    R = G * C
     if chunk_lens is None:
         chunk_lens = jnp.full((B,), C, jnp.int32)
     ppcb = max(int(pages_per_compute_block), 1)
@@ -179,46 +182,59 @@ def paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths, *,
             block_tables, ((0, 0), (0, nblocks * ppcb - max_pages)),
             constant_values=-1)
 
+    # head-major query rows: row g*C + j of kv head h is query slot j of
+    # head h*G + g, so each kv head's dot covers all of its G*C queries
+    qt = q.reshape(B, C, Hkv, G, D).transpose(0, 2, 3, 1, 4).reshape(
+        B, Hkv, R, D)
+    # in-chunk causal horizon per row: query j sits at global position
+    # lengths - chunk_lens + j and sees pos < that position + 1; padded
+    # slots (j >= chunk_lens) clamp to the full pos < lengths view
+    qpos = (lengths - chunk_lens)[:, None] + jnp.arange(C, dtype=jnp.int32)
+    limit = jnp.minimum(qpos + 1, lengths[:, None]).astype(jnp.int32)
+    limit = jnp.broadcast_to(limit[:, None, :], (B, G, C)).reshape(B, R, 1)
+
     def page_map(j):
         # each of the block's ppcb pages gets its own virtual→physical
         # translation (they are scattered in the arena)
-        def m(b, i, bt, ln, cl):
+        def m(b, i, bt, ln):
             return (jnp.maximum(bt[b, i * ppcb + j], 0), 0, 0, 0)
         return m
 
-    kv_spec = lambda j: pl.BlockSpec((1, page_size, n_kv_heads, D), page_map(j))
+    kv_spec = lambda j: pl.BlockSpec((1, page_size, Hkv, D), page_map(j))
+    row_spec = pl.BlockSpec((1, Hkv, R, D), lambda b, i, bt, ln: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=2,
         grid=(B, nblocks),
         in_specs=(
-            [pl.BlockSpec((1, C, Hq, D), lambda b, i, bt, ln, cl: (b, 0, 0, 0))]
+            [row_spec,
+             pl.BlockSpec((1, R, 1), lambda b, i, bt, ln: (b, 0, 0))]
             + [kv_spec(j) for j in range(ppcb)]
             + [kv_spec(j) for j in range(ppcb)]
         ),
-        out_specs=pl.BlockSpec((1, C, Hq, D),
-                               lambda b, i, bt, ln, cl: (b, 0, 0, 0)),
+        out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((C, Hq), jnp.float32),
-            pltpu.VMEM((C, Hq), jnp.float32),
-            pltpu.VMEM((C, Hq, D), jnp.float32),
+            pltpu.VMEM((Hkv, R, 1), jnp.float32),
+            pltpu.VMEM((Hkv, R, 1), jnp.float32),
+            pltpu.VMEM((Hkv, R, D), jnp.float32),
         ],
     )
-    kern = functools.partial(_kernel, page_size=page_size,
-                             n_kv_heads=n_kv_heads, ppcb=ppcb)
+    kern = functools.partial(_kernel, page_size=page_size, ppcb=ppcb)
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C, Hq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, R, D), q.dtype),
         interpret=interpret,
-    )(block_tables, lengths, chunk_lens, q,
+    )(block_tables, lengths, qt, limit,
       *([k_pages] * ppcb), *([v_pages] * ppcb))
+    out = out.reshape(B, Hkv, G, C, D).transpose(0, 3, 1, 2, 4).reshape(
+        B, C, Hq, D)
     return out[:, 0] if squeeze else out
 
 
 def paged_attention_sharded(q, k_pages, v_pages, block_tables, lengths, *,
                             mesh, page_size: int, n_kv_heads: int,
                             pages_per_compute_block: int = 1,
-                            interpret: bool = True, chunk_lens=None):
+                            interpret: bool, chunk_lens=None):
     """Tensor-parallel Pallas dispatch: ``shard_map`` over the mesh's 'model'
     axis, one kernel launch per shard on its LOCAL head slab.
 
@@ -233,7 +249,6 @@ def paged_attention_sharded(q, k_pages, v_pages, block_tables, lengths, *,
     it must divide the 'model' axis size (callers fall back to the unsharded
     kernel otherwise).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     tp = mesh.shape["model"]
@@ -255,9 +270,9 @@ def paged_attention_sharded(q, k_pages, v_pages, block_tables, lengths, *,
             pages_per_compute_block=pages_per_compute_block,
             interpret=interpret, chunk_lens=cl)
 
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(rep, rep, rep, heads, heads, heads),
-        out_specs=heads, check_rep=False,
+        out_specs=heads, check_vma=False,
     )(block_tables, lengths, chunk_lens, q, k_pages, v_pages)
     return out[:, 0] if squeeze else out

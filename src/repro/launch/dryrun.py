@@ -26,7 +26,7 @@ from repro.configs import (  # noqa: E402
     input_specs,
 )
 from repro.launch.hlo_analysis import analyze  # noqa: E402
-from repro.launch.mesh import make_production_mesh, mesh_context  # noqa: E402
+from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.steps import make_decode_step, make_prefill_step, make_train_step  # noqa: E402
 from repro.models import build_model  # noqa: E402
 from repro.optim import AdamWConfig, adamw_init  # noqa: E402
@@ -101,7 +101,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
         mesh = make_production_mesh(multi_pod=multi_pod)
         model = build_model(cfg)
         t0 = time.time()
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             lowered = lower_cell(cfg, model, shape, mesh,
                                  grad_compression=grad_compression)
             t_lower = time.time() - t0
@@ -109,8 +109,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
             t_compile = time.time() - t0 - t_lower
         ma = compiled.memory_analysis()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, list):  # jax < 0.5 returns a one-element list
-            ca = ca[0] if ca else {}
         txt = compiled.as_text()
         hlo = analyze(txt, n_shards_hint=mesh.shape["model"])
         rec.update(

@@ -6,34 +6,15 @@ never touches jax device state — smoke tests must keep seeing 1 CPU device.
 
 from __future__ import annotations
 
-import numpy as np
-
 import jax
+
+_AUTO = jax.sharding.AxisType.Auto
 
 
 def _make_mesh(shape, axes, devices):
-    """``jax.make_mesh`` across jax versions.
-
-    ``axis_types=`` (explicit/auto axis typing) landed in jax 0.5.x; on the
-    pinned 0.4.37 the kwarg does not exist, and every axis is implicitly
-    Auto — which is exactly what we pass on newer versions, so behaviour is
-    identical either way.
-    """
-    kwargs = {"devices": devices}
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kwargs)
-
-
-def mesh_context(mesh):
-    """Version-portable "make this the ambient mesh" context manager.
-
-    jax 0.5.x+ spells it ``jax.set_mesh(mesh)``; on the pinned 0.4.37 the
-    ``Mesh`` object is itself the context manager.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """``jax.make_mesh`` with every axis Auto (GSPMD-propagated shardings)."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(_AUTO,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -71,5 +52,4 @@ def make_serving_mesh(tp: int, devices=None):
     if len(devices) < tp:
         raise RuntimeError(
             f"tensor_parallel={tp} needs {tp} devices; have {len(devices)}")
-    return jax.sharding.Mesh(
-        np.asarray(devices[:tp]).reshape(1, tp), ("data", "model"))
+    return _make_mesh((1, tp), ("data", "model"), devices[:tp])

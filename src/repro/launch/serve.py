@@ -1,6 +1,7 @@
 """Serving driver: continuous batching over the versioned page pool.
 
-Synthesizes a batch of requests against a (reduced, by default) model and
+Synthesizes a batch of requests against a (reduced, by default;
+``--no-reduced`` for the published widths) model and
 reports throughput plus the OA counters — preemptions, reader restarts,
 warnings (pool clock) — under a configurable memory budget.  With
 ``--prefix-cache`` the requests share a common system prompt
@@ -44,6 +45,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import (DEFAULT_CLASSES, DataParallelEngine,
                            PagedServingEngine, load_trace, replay_arrivals,
@@ -112,7 +114,11 @@ def main(argv: list[str] | None = None):
     """Run the serving demo; ``argv`` overrides ``sys.argv`` (tests use it)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="shrink the architecture to a toy size (the "
+                         "default); --no-reduced serves its published "
+                         "widths")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--prompt-len", type=int, default=12)
     ap.add_argument("--max-new", type=int, default=24)
@@ -182,6 +188,7 @@ def main(argv: list[str] | None = None):
                 f"--xla_force_host_platform_device_count={need} for a "
                 f"host-simulated mesh)")
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)

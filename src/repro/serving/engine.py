@@ -36,6 +36,7 @@ from repro.core.chaos import ChaosAllocator, ChaosConfig
 from repro.core.pagepool import DEFAULT_PAGES_PER_SUPERBLOCK, DevicePagePool
 from repro.core.reclaim_policy import ReclamationPolicy, make_policy
 from repro.core.vm import ReleaseStrategy
+from repro.launch.mesh import make_serving_mesh
 from .kv_manager import KVCacheManager
 from .paged_decode import kv_storage_init
 from .runner import ModelRunner
@@ -49,7 +50,7 @@ class PagedServingEngine:
 
     def __init__(self, cfg, params, *, num_pages: int, page_size: int,
                  max_batch: int = 8, max_pages_per_seq: int | None = None,
-                 attn_impl: str = "ref", greedy: bool = True,
+                 attn_impl: str | None = None, greedy: bool = True,
                  temperature: float = 1.0, seed: int = 0,
                  pages_per_compute_block: int = 1,
                  pages_per_superblock: int = DEFAULT_PAGES_PER_SUPERBLOCK,
@@ -92,16 +93,7 @@ class PagedServingEngine:
                 raise ValueError(
                     "tensor_parallel > 1 takes a `devices` list, not a "
                     "single `device`")
-            devs = list(devices) if devices is not None else jax.devices()
-            if len(devs) < self.tensor_parallel:
-                raise RuntimeError(
-                    f"tensor_parallel={self.tensor_parallel} needs that many "
-                    f"devices; have {len(devs)}")
-            import numpy as _np
-            self.mesh = jax.sharding.Mesh(
-                _np.asarray(devs[: self.tensor_parallel]).reshape(
-                    1, self.tensor_parallel),
-                ("data", "model"))
+            self.mesh = make_serving_mesh(self.tensor_parallel, devices)
         else:
             self.mesh = None
             if device is None and devices:
@@ -150,7 +142,7 @@ class PagedServingEngine:
                 cfg, self.params, attn_impl=attn_impl, greedy=greedy,
                 temperature=temperature, seed=seed,
                 pages_per_compute_block=pages_per_compute_block,
-                mesh=self.mesh)
+                mesh=self.mesh, device=device)
             self.scheduler = Scheduler(
                 self.kv_manager, self.stats, num_pages=num_pages,
                 page_size=page_size, max_batch=max_batch,

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.ops import resolve_attn_impl
 from .kv_manager import DeviceStepState, KVCacheManager
 from .paged_decode import fused_decode_step
 
@@ -49,13 +50,17 @@ class ModelRunner:
     NOT page lifecycle: model params, attention implementation knobs, the
     sampling configuration and the per-step PRNG fold."""
 
-    def __init__(self, cfg, params, *, attn_impl: str = "ref",
+    def __init__(self, cfg, params, *, attn_impl: str | None = None,
                  greedy: bool = True, temperature: float = 1.0,
                  seed: int = 0, pages_per_compute_block: int = 1,
-                 mesh=None):
+                 mesh=None, device=None):
         self.cfg = cfg
         self.params = params
-        self.attn_impl = attn_impl
+        # attention follows the platform of the devices this runner drives
+        # (the mesh's, else its own device, else the default backend's)
+        dev = (mesh.devices.flat[0] if mesh is not None
+               else device if device is not None else jax.devices()[0])
+        self.attn_impl = resolve_attn_impl(attn_impl, dev.platform)
         self.greedy = greedy
         self.pages_per_compute_block = pages_per_compute_block
         # tensor-parallel serving: a ('data','model') mesh threads through

@@ -21,7 +21,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, reduced
-from repro.launch.mesh import make_smoke_mesh, mesh_context
+from repro.launch.mesh import make_smoke_mesh
 from repro.models import build_model
 from repro.optim import adamw_init
 from repro.sharding import rules
@@ -34,7 +34,7 @@ def named(tree, mesh):
                         is_leaf=lambda x: isinstance(x, P))
 
 mesh_a = make_smoke_mesh((2, 4), ("data", "model"))
-with mesh_context(mesh_a):
+with jax.set_mesh(mesh_a):
     params = model.init(jax.random.PRNGKey(0))
     opt = adamw_init(params)
     sh_a = named(rules.param_specs(cfg, params, mesh_a), mesh_a)
@@ -42,7 +42,7 @@ with mesh_context(mesh_a):
 
 batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0,
                                       cfg.vocab, jnp.int32)}
-with mesh_context(mesh_a):
+with jax.set_mesh(mesh_a):
     loss_a, _ = jax.jit(model.loss)(params, batch)
 
 import shutil
@@ -54,7 +54,7 @@ cm.save(7, (params, opt), blocking=True)
 mesh_b = make_smoke_mesh((4, 2), ("data", "model"))
 like = jax.eval_shape(lambda: (model.init(jax.random.PRNGKey(0)),
                                adamw_init(model.init(jax.random.PRNGKey(0)))))
-with mesh_context(mesh_b):
+with jax.set_mesh(mesh_b):
     sh_b = (named(rules.param_specs(cfg, like[0], mesh_b), mesh_b),
             {"m": named(rules.param_specs(cfg, like[0], mesh_b), mesh_b),
              "v": named(rules.param_specs(cfg, like[0], mesh_b), mesh_b),

@@ -8,9 +8,11 @@ see tests/conftest.py); every invariant here is device-count independent.
 
 import dataclasses
 
+import hypothesis.strategies as st
 import jax
 import numpy as np
 import pytest
+from hypothesis import HealthCheck, given, settings
 
 from repro.configs import get_config, reduced
 from repro.core.vm import superblock_floor
@@ -150,40 +152,20 @@ def test_per_replica_release_floor_matches_single_pool(params):
 
 
 # ---------------------------------------------------------------------------
-# hypothesis: random interleavings across the fleet (skips alone when the
-# dependency is absent — the deterministic tests above must still run)
-
-try:
-    import hypothesis.strategies as st
-    from hypothesis import HealthCheck, given, settings
-    _HYP_DECOS = [
-        given(n_replicas=st.integers(2, 4),
-              ops=st.lists(st.one_of(
-                  st.tuples(st.just("submit"), st.integers(0, 3),
-                            st.integers(1, 5)),
-                  st.tuples(st.just("step"), st.just(0), st.just(0)),
-                  st.tuples(st.just("preempt"), st.integers(0, 3),
-                            st.just(0)),
-              ), min_size=1, max_size=10)),
-        settings(max_examples=8, deadline=None,
-                 suppress_health_check=[HealthCheck.too_slow]),
-    ]
-except ImportError:
-    _HYP_DECOS = [pytest.mark.skip(
-        reason="property tests need hypothesis (requirements-dev.txt)")]
+# hypothesis: random interleavings across the fleet
 
 
-def _apply(decos):
-    def inner(fn):
-        for d in reversed(decos):
-            fn = d(fn)
-        return fn
-    return inner
-
-
-@_apply(_HYP_DECOS)
-def test_random_interleavings_conserve_pages_per_pool(params, n_replicas=2,
-                                                      ops=()):
+@given(n_replicas=st.integers(2, 4),
+       ops=st.lists(st.one_of(
+           st.tuples(st.just("submit"), st.integers(0, 3), st.integers(1, 5)),
+           st.tuples(st.just("step"), st.just(0), st.just(0)),
+           st.tuples(st.just("preempt"), st.integers(0, 3), st.just(0)),
+       ), min_size=1, max_size=10))
+@settings(max_examples=8, deadline=None,
+          suppress_health_check=[HealthCheck.too_slow,
+                                 HealthCheck.function_scoped_fixture])
+def test_random_interleavings_conserve_pages_per_pool(params, n_replicas,
+                                                      ops):
     """Random submit/step/preempt interleavings across 2–4 replicas: after
     every fleet step each pool's pages balance exactly (free + distinct
     live == mapped), no page crosses a pool, and the drained fleet releases

@@ -20,6 +20,8 @@ invariants are fuzzed over random interleavings.
 
 import numpy as np
 import pytest
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.pagepool import DevicePagePool
 from repro.core.reclaim_policy import (INTERVAL_LAG, EpochGracePolicy,
@@ -197,28 +199,8 @@ def test_make_policy_env_default(monkeypatch):
 
 # -- fuzzed interleavings ----------------------------------------------------
 #
-# With ``hypothesis`` installed these run as real property tests over random
-# interleavings; without it (the minimal image does not bake it in, and
-# installing is out of scope) the SAME checkers run over a seeded numpy
-# sample of interleavings — weaker shrinking, same invariant coverage, and
-# the deterministic scripted tests above always run either way.
-
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:  # pragma: no cover - depends on the environment
-    HAVE_HYPOTHESIS = False
-
-
-def _random_sequences(alphabet, max_len, n, seed):
-    rng = np.random.default_rng(seed)
-    out = []
-    for _ in range(n):
-        k = int(rng.integers(0, max_len + 1))
-        out.append([alphabet[i]
-                    for i in rng.integers(0, len(alphabet), size=k)])
-    return out
+# Fuzzed with hypothesis over random interleavings; the deterministic
+# scripted tests above pin the known cases.
 
 
 def _interval_invariant(ops):
@@ -249,37 +231,21 @@ def _interval_invariant(ops):
             ia.map(1)
 
 
-if HAVE_HYPOTHESIS:
+@given(st.lists(st.sampled_from(["reclaim", "step"]), max_size=60))
+@settings(max_examples=200, deadline=None)
+def test_epoch_property_no_skip_across_reclaim(events):
+    """Fuzzed grace-period soundness: no random reclaim/step
+    interleaving makes epoch-grace skip a step with an unvalidated
+    reclaim outstanding."""
+    _check_epoch_sequence(events)
 
-    @given(st.lists(st.sampled_from(["reclaim", "step"]), max_size=60))
-    @settings(max_examples=200, deadline=None)
-    def test_epoch_property_no_skip_across_reclaim(events):
-        """Fuzzed grace-period soundness: no random reclaim/step
-        interleaving makes epoch-grace skip a step with an unvalidated
-        reclaim outstanding."""
-        _check_epoch_sequence(events)
 
-    @given(st.lists(
-        st.sampled_from(["alloc", "free", "tick", "release", "map"]),
-        max_size=30))
-    @settings(max_examples=25, deadline=None)
-    def test_interval_property_no_early_regrant(ops):
-        """Fuzzed IBR soundness: across random alloc/free/tick/release/map
-        interleavings, a page freed at interval i is never granted again
-        before interval i + 2."""
-        _interval_invariant(ops)
-
-else:
-
-    def test_epoch_property_no_skip_across_reclaim():
-        """Seeded-sample fallback of the epoch grace-period property."""
-        for events in _random_sequences(["reclaim", "step"], 60, 200,
-                                        seed=0):
-            _check_epoch_sequence(events)
-
-    def test_interval_property_no_early_regrant():
-        """Seeded-sample fallback of the IBR no-early-regrant property."""
-        for ops in _random_sequences(
-                ["alloc", "free", "tick", "release", "map"], 30, 25,
-                seed=1):
-            _interval_invariant(ops)
+@given(st.lists(
+    st.sampled_from(["alloc", "free", "tick", "release", "map"]),
+    max_size=30))
+@settings(max_examples=25, deadline=None)
+def test_interval_property_no_early_regrant(ops):
+    """Fuzzed IBR soundness: across random alloc/free/tick/release/map
+    interleavings, a page freed at interval i is never granted again
+    before interval i + 2."""
+    _interval_invariant(ops)

@@ -79,7 +79,7 @@ import json
 import jax
 from repro.configs import get_config, reduced, SHAPES, input_specs, decode_cache_size
 from repro.launch.dryrun import lower_cell
-from repro.launch.mesh import make_smoke_mesh, mesh_context
+from repro.launch.mesh import make_smoke_mesh
 from repro.models import build_model
 import dataclasses
 
@@ -92,7 +92,7 @@ for arch in ("olmo-1b", "mixtral-8x7b", "mamba2-780m"):
     for shape_name in ("train_4k", "decode_32k"):
         sh = SHAPES[shape_name]
         sh = dataclasses.replace(sh, seq_len=64, global_batch=4)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             lowered = lower_cell(cfg, model, sh, mesh)
             compiled = lowered.compile()
         out[f"{arch}/{shape_name}"] = compiled.memory_analysis().temp_size_in_bytes

@@ -22,6 +22,7 @@ import dataclasses
 import jax
 import numpy as np
 import pytest
+from hypothesis import HealthCheck, given, settings, strategies as st
 
 from repro.configs import get_config, reduced
 from repro.models import build_model
@@ -249,13 +250,6 @@ def test_ngram_drafter_prompt_lookup():
 # ---------------------------------------------------------------------------
 # property test: variable per-row accepted counts against the oracle
 
-try:  # optional dep: skip ONLY the property test, never the module
-    from hypothesis import HealthCheck, given, settings, strategies as st
-    HAS_HYPOTHESIS = True
-except ImportError:  # pragma: no cover - exercised where hypothesis absent
-    HAS_HYPOTHESIS = False
-
-
 class ScriptedDrafter:
     """Proposes the ORACLE continuation corrupted at a scripted depth, so
     each call's accepted count is exactly ``min(depth, k, remaining)`` —
@@ -313,12 +307,8 @@ def _property_body(params, data):
     assert (rc > 0).sum() == cached  # only the prefix cache holds pages
 
 
-if HAS_HYPOTHESIS:
-    @settings(max_examples=8, deadline=None,
-              suppress_health_check=[HealthCheck.function_scoped_fixture])
-    @given(data=st.data())
-    def test_variable_accept_counts_match_oracle(params, data):
-        _property_body(params, data)
-else:  # keep the test id visible (as a skip) where hypothesis is absent
-    def test_variable_accept_counts_match_oracle(params):
-        pytest.skip("hypothesis not installed")
+@settings(max_examples=8, deadline=None,
+          suppress_health_check=[HealthCheck.function_scoped_fixture])
+@given(data=st.data())
+def test_variable_accept_counts_match_oracle(params, data):
+    _property_body(params, data)

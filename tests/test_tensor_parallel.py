@@ -23,8 +23,8 @@ import sys
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ARCH_IDS, get_config
@@ -99,40 +99,19 @@ def _assert_specs_divisible(cfg, params, mesh):
             assert dim % n == 0, (cfg.name, leaf.shape, spec)
 
 
-try:
-    from hypothesis import given, settings, strategies as st
-
-    @settings(max_examples=40, deadline=None)
-    @given(arch=st.sampled_from(list(ARCH_IDS)),
-           tp=st.sampled_from([1, 2, 3, 4, 5, 6, 7, 8, 12, 16, 48]),
-           data=st.sampled_from([1, 2, 3, 8]))
-    def test_param_specs_never_nondivisible(arch, tp, data):
-        """Property: for ANY (arch, mesh shape), param_specs never emits a
-        sharded dim the mesh axis product does not divide — fallback to
-        replication is the contract, crashing device_put is a bug."""
-        mesh = _FakeMesh({"data": data, "model": tp})
-        cfg = get_config(arch)
-        params = jax.eval_shape(
-            lambda c=cfg: build_model(c).init(jax.random.PRNGKey(0)))
-        _assert_specs_divisible(cfg, params, mesh)
-
-except ImportError:  # hypothesis not installed: seeded exhaustive-ish sweep
-
-    def test_param_specs_never_nondivisible():
-        """Property (seeded fallback, no hypothesis in this container): for
-        ANY (arch, mesh shape), param_specs never emits a sharded dim the
-        mesh axis product does not divide — fallback to replication is the
-        contract, crashing device_put is a bug."""
-        rng = np.random.default_rng(0)
-        shapes = {a: jax.eval_shape(
-            lambda c=get_config(a): build_model(c).init(jax.random.PRNGKey(0)))
-            for a in ARCH_IDS}
-        for _ in range(40):
-            arch = ARCH_IDS[int(rng.integers(len(ARCH_IDS)))]
-            tp = int(rng.choice([1, 2, 3, 4, 5, 6, 7, 8, 12, 16, 48]))
-            data = int(rng.choice([1, 2, 3, 8]))
-            mesh = _FakeMesh({"data": data, "model": tp})
-            _assert_specs_divisible(get_config(arch), shapes[arch], mesh)
+@settings(max_examples=40, deadline=None)
+@given(arch=st.sampled_from(list(ARCH_IDS)),
+       tp=st.sampled_from([1, 2, 3, 4, 5, 6, 7, 8, 12, 16, 48]),
+       data=st.sampled_from([1, 2, 3, 8]))
+def test_param_specs_never_nondivisible(arch, tp, data):
+    """Property: for ANY (arch, mesh shape), param_specs never emits a
+    sharded dim the mesh axis product does not divide — fallback to
+    replication is the contract, crashing device_put is a bug."""
+    mesh = _FakeMesh({"data": data, "model": tp})
+    cfg = get_config(arch)
+    params = jax.eval_shape(
+        lambda c=cfg: build_model(c).init(jax.random.PRNGKey(0)))
+    _assert_specs_divisible(cfg, params, mesh)
 
 
 # ------------------------------------------------------- sharded kernel

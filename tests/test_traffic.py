@@ -20,6 +20,8 @@ import time
 
 import numpy as np
 import pytest
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.serving import (DEFAULT_CLASSES, ClassQueues, ClassStats,
                            DegradationLadder, EngineStats, LadderConfig,
@@ -156,18 +158,7 @@ def test_strict_priority_drain_order():
     assert not q and len(q) == 0
 
 
-# With ``hypothesis`` installed the properties below run as real fuzzed
-# property tests; without it (the minimal image does not bake it in, and
-# installing is out of scope) the SAME checkers run over a seeded numpy
-# sample of inputs — weaker shrinking, same invariant coverage.  The
-# deterministic scripted tests elsewhere in this file always run either way.
-
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:  # pragma: no cover - depends on the environment
-    HAVE_HYPOTHESIS = False
+# Property tests: the checkers below run over hypothesis-generated inputs.
 
 
 def _check_priority_never_starved(classes):
@@ -223,49 +214,25 @@ def _check_ladder_monotone(pressures):
         prev = lvl
 
 
-if HAVE_HYPOTHESIS:
+@settings(max_examples=50, deadline=None)
+@given(st.lists(st.sampled_from(CLS_NAMES), min_size=1, max_size=12))
+def test_prop_high_priority_never_starved_by_lower(classes):
+    _check_priority_never_starved(classes)
 
-    @settings(max_examples=50, deadline=None)
-    @given(st.lists(st.sampled_from(CLS_NAMES), min_size=1, max_size=12))
-    def test_prop_high_priority_never_starved_by_lower(classes):
-        _check_priority_never_starved(classes)
 
-    @settings(max_examples=50, deadline=None)
-    @given(st.lists(st.tuples(st.sampled_from(CLS_NAMES), st.booleans()),
-                    min_size=1, max_size=30),
-           st.integers(min_value=1, max_value=3))
-    def test_prop_bounded_queue_never_exceeds_cap(ops, cap):
-        _check_bounded_queue(ops, cap)
+@settings(max_examples=50, deadline=None)
+@given(st.lists(st.tuples(st.sampled_from(CLS_NAMES), st.booleans()),
+                min_size=1, max_size=30),
+       st.integers(min_value=1, max_value=3))
+def test_prop_bounded_queue_never_exceeds_cap(ops, cap):
+    _check_bounded_queue(ops, cap)
 
-    @settings(max_examples=100, deadline=None)
-    @given(st.lists(st.floats(min_value=0.0, max_value=2.0,
-                              allow_nan=False), min_size=1, max_size=60))
-    def test_prop_ladder_moves_one_rung_at_a_time(pressures):
-        _check_ladder_monotone(pressures)
 
-else:
-
-    def test_prop_high_priority_never_starved_by_lower():
-        rng = np.random.default_rng(0)
-        for _ in range(25):
-            k = int(rng.integers(1, 13))
-            _check_priority_never_starved(
-                [CLS_NAMES[i] for i in rng.integers(0, len(CLS_NAMES),
-                                                    size=k)])
-
-    def test_prop_bounded_queue_never_exceeds_cap():
-        rng = np.random.default_rng(1)
-        for _ in range(25):
-            k = int(rng.integers(1, 31))
-            ops = [(CLS_NAMES[int(rng.integers(0, len(CLS_NAMES)))],
-                    bool(rng.integers(0, 2))) for _ in range(k)]
-            _check_bounded_queue(ops, cap=int(rng.integers(1, 4)))
-
-    def test_prop_ladder_moves_one_rung_at_a_time():
-        rng = np.random.default_rng(2)
-        for _ in range(50):
-            k = int(rng.integers(1, 61))
-            _check_ladder_monotone(list(rng.uniform(0.0, 2.0, size=k)))
+@settings(max_examples=100, deadline=None)
+@given(st.lists(st.floats(min_value=0.0, max_value=2.0,
+                          allow_nan=False), min_size=1, max_size=60))
+def test_prop_ladder_moves_one_rung_at_a_time(pressures):
+    _check_ladder_monotone(pressures)
 
 
 # ---------------------------------------------------------------------------
