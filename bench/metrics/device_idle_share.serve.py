@@ -1,0 +1,3 @@
+"""Device: one minus the busy union of the chip's operations over the traced window (open-loop cells)."""
+
+from bench.readers import device_idle_share as read  # noqa: F401

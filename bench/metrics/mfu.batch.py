@@ -1,0 +1,3 @@
+"""Model step: model operations of the window's tokens over the chips' bf16 peak for the window's length (closed-loop cells)."""
+
+from bench.readers import mfu as read  # noqa: F401
