@@ -180,6 +180,22 @@ def pool_init(num_pages: int,
 # allocation: one-pass segmented pop over a superblock priority ordering
 
 
+def _scope(name: str):
+    """Trace the body under ``jax.named_scope(name)``: every device op of
+    a pool operation carries ``pool.<op>`` in its op name, whether it runs
+    as the pool's own program or inline in a fused step.  A fresh scope
+    per call: ``named_scope``'s object keeps the outer name stack on
+    itself, so one shared as a decorator is unsafe when two threads
+    trace at once."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
+
+
 def _alloc_order(pool: PagePool):
     """Priority ordering of superblocks for allocation.
 
@@ -226,6 +242,7 @@ def _segmented_pop_impl(pool: PagePool, total: jax.Array, max_total: int):
                          page_refcount=refcount), pages
 
 
+@_scope("pool.alloc")
 def _alloc_pages_batch_impl(pool: PagePool, need: jax.Array, max_grow: int):
     """Traceable body of :func:`alloc_pages_batch` (reused inside fused jits)."""
     B = need.shape[0]
@@ -264,6 +281,7 @@ def alloc_pages_batch(pool: PagePool, need: jax.Array, max_grow: int = 1):
     return _alloc_pages_batch_impl(pool, need, max_grow)
 
 
+@_scope("pool.alloc")
 def _alloc_pages_impl(pool: PagePool, n: int):
     ok = _free_total(pool) >= n
     pool, pages = _segmented_pop_impl(
@@ -288,6 +306,7 @@ def alloc_pages(pool: PagePool, n: int):
 # list only on the refcount ZERO-TRANSITION
 
 
+@_scope("pool.unshare")
 def _unshare_pages_impl(pool: PagePool, pages: jax.Array) -> PagePool:
     """Traceable body of :func:`unshare_pages` (reused inside fused jits).
 
@@ -355,7 +374,7 @@ def unshare_pages(pool: PagePool, pages: jax.Array) -> PagePool:
 # free == unshare: with every grant starting at refcount 1, freeing a
 # solely-owned page is exactly the zero-transition decref.  The alias keeps
 # the paper-facing vocabulary ("retire/free") alongside the sharing one.
-_free_pages_impl = _unshare_pages_impl
+_free_pages_impl = _scope("pool.free")(_unshare_pages_impl)
 
 
 @functools.partial(jax.jit, donate_argnums=0)
@@ -367,9 +386,10 @@ def free_pages(pool: PagePool, pages: jax.Array) -> PagePool:
     version bump + clock tick, the page immediately re-allocatable.  For
     pages with extra holders (``share_pages``) only the caller's reference
     is dropped."""
-    return _unshare_pages_impl(pool, pages)
+    return _free_pages_impl(pool, pages)
 
 
+@_scope("pool.share")
 def _share_pages_impl(pool: PagePool, pages: jax.Array):
     """Traceable body of :func:`share_pages` (reused inside fused jits)."""
     pages = pages.reshape(-1).astype(jnp.int32)
@@ -402,6 +422,7 @@ def share_pages(pool: PagePool, pages: jax.Array):
 # physical release accounting (paper §3.2, device edition)
 
 
+@_scope("pool.release")
 def _release_empty_impl(pool: PagePool, max_release: jax.Array,
                         keep_mapped: jax.Array):
     S, K = pool.sb_pages.shape
@@ -455,6 +476,7 @@ def release_empty_superblocks(pool: PagePool, max_release: jax.Array,
     return _release_empty_impl(pool, max_release, keep_mapped)
 
 
+@_scope("pool.map")
 def _map_superblocks_impl(pool: PagePool, n: jax.Array):
     cap = _capacities(pool)
     unmapped = ~pool.sb_mapped
@@ -479,6 +501,7 @@ def map_superblocks(pool: PagePool, n: jax.Array):
 # OA snapshot / validate (unchanged by the superblock refactor)
 
 
+@_scope("pool.snapshot")
 def _snapshot_impl(pool: PagePool, pages: jax.Array) -> jax.Array:
     return jnp.where(pages >= 0, pool.page_version[jnp.maximum(pages, 0)], 0)
 
@@ -489,6 +512,7 @@ def snapshot_versions(pool: PagePool, pages: jax.Array) -> jax.Array:
     return _snapshot_impl(pool, pages)
 
 
+@_scope("pool.validate")
 def _validate_and_commit_impl(pool: PagePool, pages: jax.Array,
                               snapshot: jax.Array):
     cur = _snapshot_impl(pool, pages)
@@ -516,8 +540,10 @@ def validate_read(pool: PagePool, pages: jax.Array, snapshot: jax.Array) -> jax.
     superblock release bumps it again BEFORE the range leaves circulation —
     so a stale optimistic read is always caught — the warning-before-free
     order of Alg. 1.)"""
-    cur = jnp.where(pages >= 0, pool.page_version[jnp.maximum(pages, 0)], 0)
-    return jnp.all(cur == snapshot)
+    with jax.named_scope("pool.validate"):
+        cur = jnp.where(pages >= 0,
+                        pool.page_version[jnp.maximum(pages, 0)], 0)
+        return jnp.all(cur == snapshot)
 
 
 # ---------------------------------------------------------------------------

@@ -224,6 +224,7 @@ def paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, R, D), q.dtype),
         interpret=interpret,
+        name=f"paged_attention_c{C}",
     )(block_tables, lengths, qt, limit,
       *([k_pages] * ppcb), *([v_pages] * ppcb))
     out = out.reshape(B, Hkv, G, C, D).transpose(0, 3, 1, 2, 4).reshape(

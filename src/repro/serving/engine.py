@@ -41,6 +41,7 @@ from .kv_manager import KVCacheManager
 from .paged_decode import kv_storage_init
 from .runner import ModelRunner
 from .scheduler import Request, Scheduler  # noqa: F401  (re-export)
+from .spans import span
 from .stats import EngineStats
 
 
@@ -156,7 +157,8 @@ class PagedServingEngine:
                 spec_probe_interval=spec_probe_interval,
                 reclaim_policy=policy, classes=classes,
                 max_queue_depth=max_queue_depth,
-                victim_policy=victim_policy, ladder=ladder, clock=clock)
+                victim_policy=victim_policy, ladder=ladder, clock=clock,
+                span=span)
 
     # -- scheduling (delegates to the policy layer) --------------------------
 

@@ -157,24 +157,28 @@ def _chunk_core(params, kv, block_tables, lengths, tokens, n_new, *, cfg,
 
     def layer(x, scanned):
         blk, kl, vl = scanned  # kl/vl [P, page, Hkv, D]
-        h = apply_norm(cfg, x, blk["ln1"])
-        q, k, v = attention_qkv(cfg, h, blk["attn"], positions)
-        kl = kl.at[pidx, slot].set(k, mode="drop")
-        vl = vl.at[pidx, slot].set(v, mode="drop")
-        att = paged_attention(q, {"k": kl, "v": vl}, block_tables,
-                              total_len, impl=impl,
-                              pages_per_compute_block=pages_per_compute_block,
-                              chunk_lens=n_new, mesh=mesh)
-        x = x + att.reshape(B, C, -1) @ blk["attn"]["wo"]
-        h2 = apply_norm(cfg, x, blk["ln2"])
-        if cfg.moe:
-            from repro.models.moe import moe_apply
-            # dropless: the serving path must compute the same per-token
-            # function regardless of chunk width (decode-parity contract)
-            y, _ = moe_apply(cfg, h2, blk["moe"], dropless=True)
-        else:
-            y = mlp_apply(cfg, h2, blk["mlp"])
-        return x + y, (kl, vl)
+        with jax.named_scope("layer.kv_write"):
+            h = apply_norm(cfg, x, blk["ln1"])
+            q, k, v = attention_qkv(cfg, h, blk["attn"], positions)
+            kl = kl.at[pidx, slot].set(k, mode="drop")
+            vl = vl.at[pidx, slot].set(v, mode="drop")
+        with jax.named_scope("layer.attention"):
+            att = paged_attention(
+                q, {"k": kl, "v": vl}, block_tables, total_len, impl=impl,
+                pages_per_compute_block=pages_per_compute_block,
+                chunk_lens=n_new, mesh=mesh)
+            x = x + att.reshape(B, C, -1) @ blk["attn"]["wo"]
+        with jax.named_scope("layer.mlp"):
+            h2 = apply_norm(cfg, x, blk["ln2"])
+            if cfg.moe:
+                from repro.models.moe import moe_apply
+                # dropless: the serving path must compute the same
+                # per-token function regardless of chunk width
+                # (decode-parity contract)
+                y, _ = moe_apply(cfg, h2, blk["moe"], dropless=True)
+            else:
+                y = mlp_apply(cfg, h2, blk["mlp"])
+            return x + y, (kl, vl)
 
     x, (ks, vs) = jax.lax.scan(layer, x, (params["blocks"], kv["k"], kv["v"]))
     x = apply_norm(cfg, x, params["final_norm"])
@@ -303,88 +307,99 @@ def fused_decode_step(params, kv, pool, block_tables, snapshot, lengths,
     num_pages = kv["k"].shape[1]
     C = max(int(chunk_size), 1)
     MG = max_chunk_pages(C, page_size)
-    rows = jnp.arange(B)
 
     # (1) per-row chunk sizing (device-side: no host knowledge of lengths).
     # With speculation a DECODING row's chunk holds 1 + dlens tokens: its
     # last committed token plus the drafts to verify.
-    budget = jnp.clip(jnp.asarray(chunk_budget, jnp.int32), 1, C)
-    prefilling = lengths < prompt_len
-    if speculative:
-        dlens = jnp.where(active & ~prefilling,
-                          jnp.clip(draft_lens, 0, C - 1), 0).astype(jnp.int32)
-        decode_n = 1 + dlens
-    else:
-        decode_n = 1
-    n_new = jnp.where(active & prefilling,
-                      jnp.minimum(budget, prompt_len - lengths),
-                      decode_n).astype(jnp.int32)
+    with jax.named_scope("step.inputs"):
+        budget = jnp.clip(jnp.asarray(chunk_budget, jnp.int32), 1, C)
+        prefilling = lengths < prompt_len
+        if speculative:
+            dlens = jnp.where(active & ~prefilling,
+                              jnp.clip(draft_lens, 0, C - 1),
+                              0).astype(jnp.int32)
+            decode_n = 1 + dlens
+        else:
+            decode_n = 1
+        n_new = jnp.where(active & prefilling,
+                          jnp.minimum(budget, prompt_len - lengths),
+                          decode_n).astype(jnp.int32)
 
     # (2) batched multi-page growth + COW — one fused alloc_pages_batch for
     # every page the batch's chunks touch
-    p0 = lengths // page_size
-    plast = (lengths + n_new - 1) // page_size
-    koff = jnp.arange(MG, dtype=jnp.int32)
-    pis = p0[:, None] + koff[None, :]  # [B, MG] candidate page slots
-    in_range = (pis <= plast[:, None]) & (pis < M)
-    cur = jnp.take_along_axis(block_tables, jnp.minimum(pis, M - 1), axis=1)
-    cur0 = cur[:, 0]
-    rc0 = pool.page_refcount[jnp.clip(cur0, 0, num_pages - 1)]
-    # the chunk's FIRST written page is the only one that can be mapped yet
-    # shared (pages past the committed length are unmapped): diverge onto a
-    # private copy before the KV append below can touch it
-    need_copy = active & (cur0 >= 0) & (rc0 > 1)
-    need_slot = in_range & (cur < 0) & active[:, None]
-    need_slot = need_slot | (need_copy[:, None] & (koff == 0)[None, :])
-    need = jnp.sum(need_slot, axis=1).astype(jnp.int32)
-    pool, grants, _ = pp._alloc_pages_batch_impl(pool, need, MG)
-    # pack each row's grants onto its needing slots, in page order
-    gidx = jnp.cumsum(need_slot, axis=1) - 1
-    g = jnp.take_along_axis(grants, jnp.clip(gidx, 0, MG - 1), axis=1)
-    g = jnp.where(need_slot, g, -1).astype(jnp.int32)
-    grant_n = jnp.sum((g >= 0).astype(jnp.int32), axis=1)
-    grant_ok = (need == 0) | (grant_n == need)  # all-or-nothing per row
-    # COW: copy the shared page's KV into the fresh copy (whole-page
-    # gather/scatter across all layers; OOB src/dst rows are dropped)
-    cow = need_copy & (g[:, 0] >= 0)
-    src = jnp.where(cow, cur0, num_pages)
-    dst = jnp.where(cow, g[:, 0], num_pages)
-    src_c = jnp.clip(src, 0, num_pages - 1)
-    kv = {"k": kv["k"].at[:, dst].set(kv["k"][:, src_c], mode="drop"),
-          "v": kv["v"].at[:, dst].set(kv["v"][:, src_c], mode="drop")}
-    # ...and drop the row's reference on the original (other holders keep
-    # their versions valid; if this was the LAST reference the page frees
-    # and its version bumps — correct either way, all in this dispatch)
-    pool = pp._unshare_pages_impl(pool, jnp.where(cow, cur0, -1))
-    # install the grants and fold their versions into the snapshot
-    pis_w = jnp.where(g >= 0, pis, M)  # column M = OOB -> dropped scatter
-    block_tables = block_tables.at[rows[:, None], pis_w].set(g, mode="drop")
-    vers = pool.page_version[jnp.clip(g, 0, num_pages - 1)]
-    snapshot = snapshot.at[rows[:, None], pis_w].set(
-        vers.astype(jnp.uint32), mode="drop")
-    grant_info = jnp.where(grant_ok, grant_n, -1).astype(jnp.int32)
+    with jax.named_scope("step.grant"):
+        rows = jnp.arange(B)
+        p0 = lengths // page_size
+        plast = (lengths + n_new - 1) // page_size
+        koff = jnp.arange(MG, dtype=jnp.int32)
+        pis = p0[:, None] + koff[None, :]  # [B, MG] candidate page slots
+        in_range = (pis <= plast[:, None]) & (pis < M)
+        cur = jnp.take_along_axis(block_tables, jnp.minimum(pis, M - 1),
+                                  axis=1)
+        cur0 = cur[:, 0]
+        rc0 = pool.page_refcount[jnp.clip(cur0, 0, num_pages - 1)]
+        # the chunk's FIRST written page is the only one that can be mapped
+        # yet shared (pages past the committed length are unmapped): diverge
+        # onto a private copy before the KV append below can touch it
+        need_copy = active & (cur0 >= 0) & (rc0 > 1)
+        need_slot = in_range & (cur < 0) & active[:, None]
+        need_slot = need_slot | (need_copy[:, None] & (koff == 0)[None, :])
+        need = jnp.sum(need_slot, axis=1).astype(jnp.int32)
+        pool, grants, _ = pp._alloc_pages_batch_impl(pool, need, MG)
+        # pack each row's grants onto its needing slots, in page order
+        gidx = jnp.cumsum(need_slot, axis=1) - 1
+        g = jnp.take_along_axis(grants, jnp.clip(gidx, 0, MG - 1), axis=1)
+        g = jnp.where(need_slot, g, -1).astype(jnp.int32)
+        grant_n = jnp.sum((g >= 0).astype(jnp.int32), axis=1)
+        grant_ok = (need == 0) | (grant_n == need)  # all-or-nothing per row
+        # COW: copy the shared page's KV into the fresh copy (whole-page
+        # gather/scatter across all layers; OOB src/dst rows are dropped)
+        cow = need_copy & (g[:, 0] >= 0)
+        with jax.named_scope("kv.cow"):
+            src = jnp.where(cow, cur0, num_pages)
+            dst = jnp.where(cow, g[:, 0], num_pages)
+            src_c = jnp.clip(src, 0, num_pages - 1)
+            kv = {"k": kv["k"].at[:, dst].set(kv["k"][:, src_c],
+                                              mode="drop"),
+                  "v": kv["v"].at[:, dst].set(kv["v"][:, src_c],
+                                              mode="drop")}
+        # ...and drop the row's reference on the original (other holders
+        # keep their versions valid; if this was the LAST reference the page
+        # frees and its version bumps — correct either way, all in this
+        # dispatch)
+        pool = pp._unshare_pages_impl(pool, jnp.where(cow, cur0, -1))
+        # install the grants and fold their versions into the snapshot
+        pis_w = jnp.where(g >= 0, pis, M)  # column M = OOB: dropped scatter
+        block_tables = block_tables.at[rows[:, None], pis_w].set(
+            g, mode="drop")
+        vers = pool.page_version[jnp.clip(g, 0, num_pages - 1)]
+        snapshot = snapshot.at[rows[:, None], pis_w].set(
+            vers.astype(jnp.uint32), mode="drop")
+        grant_info = jnp.where(grant_ok, grant_n, -1).astype(jnp.int32)
 
     # (3) next input tokens: replay the prompt, then feed back the sample.
     # The position clamp is for DECODE rows' padded lanes (their positions
     # legitimately exceed the buffer — the where() discards them); admission
     # guarantees every real prompt position fits (engine.submit rejects
     # prompts beyond capacity instead of silently clamping).
-    cap = prompt_buf.shape[1]
-    pos = lengths[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
-    ppos = jnp.minimum(pos, cap - 1)
-    ptok = jnp.take_along_axis(prompt_buf, ppos, axis=1)
-    if speculative:
-        # decode rows' chunk inputs: last committed token, then the drafts
-        gen_in = jnp.concatenate([last_tok[:, None], draft_toks], axis=1)
-    else:
-        gen_in = last_tok[:, None]
-    tok_in = jnp.where(pos < prompt_len[:, None], ptok, gen_in)
+    with jax.named_scope("step.inputs"):
+        cap = prompt_buf.shape[1]
+        pos = lengths[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
+        ppos = jnp.minimum(pos, cap - 1)
+        ptok = jnp.take_along_axis(prompt_buf, ppos, axis=1)
+        if speculative:
+            # decode rows' chunk inputs: last committed token, then drafts
+            gen_in = jnp.concatenate([last_tok[:, None], draft_toks], axis=1)
+        else:
+            gen_in = last_tok[:, None]
+        tok_in = jnp.where(pos < prompt_len[:, None], ptok, gen_in)
 
     # (4) model math (starved rows' appends are masked — see _chunk_core)
-    x, kv = _chunk_core(
-        params, kv, block_tables, lengths, tok_in, n_new, cfg=cfg, impl=impl,
-        pages_per_compute_block=pages_per_compute_block, write_ok=grant_ok,
-        mesh=mesh)
+    with jax.named_scope("step.layers"):
+        x, kv = _chunk_core(
+            params, kv, block_tables, lengths, tok_in, n_new, cfg=cfg,
+            impl=impl, pages_per_compute_block=pages_per_compute_block,
+            write_ok=grant_ok, mesh=mesh)
 
     # (5) on-device token selection.  Plain path: only the chunk's last
     # live position is unembedded — logits never leave the device.
@@ -393,29 +408,32 @@ def fused_decode_step(params, kv, pool, block_tables, snapshot, lengths,
     # per-slot verdicts into a committed prefix length (the sequence-axis
     # validate_and_commit).  The sampled token is the BONUS token from the
     # accept point (for prefilling rows: from the last live slot, as ever).
-    if speculative:
-        tgt = jnp.argmax(unembed(cfg, params, x).astype(jnp.float32),
-                         axis=-1).astype(jnp.int32)  # [B, C]
-        n_acc = speculative_accept(tgt, tok_in, dlens)
-        sel = jnp.where(prefilling, jnp.clip(n_new - 1, 0, C - 1), n_acc)
-        nxt = jnp.take_along_axis(tgt, sel[:, None], axis=1)[:, 0]
-        commit_n = jnp.where(prefilling, n_new, n_acc + 1).astype(jnp.int32)
-    else:
-        last_idx = jnp.clip(n_new - 1, 0, C - 1)
-        xl = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
-        logits = unembed(cfg, params, xl)[:, 0].astype(jnp.float32)
-        if greedy:
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("step.unembed"):
+        if speculative:
+            tgt = jnp.argmax(unembed(cfg, params, x).astype(jnp.float32),
+                             axis=-1).astype(jnp.int32)  # [B, C]
+            n_acc = speculative_accept(tgt, tok_in, dlens)
+            sel = jnp.where(prefilling, jnp.clip(n_new - 1, 0, C - 1),
+                            n_acc)
+            nxt = jnp.take_along_axis(tgt, sel[:, None], axis=1)[:, 0]
+            commit_n = jnp.where(prefilling, n_new,
+                                 n_acc + 1).astype(jnp.int32)
         else:
-            nxt = jax.random.categorical(
-                key, logits / jnp.maximum(temperature, 1e-6), axis=-1
-            ).astype(jnp.int32)
-        n_acc = jnp.zeros_like(lengths)
-        commit_n = n_new
-    # a row's sample is a real next token only once its chunk reaches the
-    # final prompt token (decode rows always; prefilling rows exactly on the
-    # step their prompt completes)
-    samples = (lengths + n_new) >= prompt_len
+            last_idx = jnp.clip(n_new - 1, 0, C - 1)
+            xl = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
+            logits = unembed(cfg, params, xl)[:, 0].astype(jnp.float32)
+            if greedy:
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            else:
+                nxt = jax.random.categorical(
+                    key, logits / jnp.maximum(temperature, 1e-6), axis=-1
+                ).astype(jnp.int32)
+            n_acc = jnp.zeros_like(lengths)
+            commit_n = n_new
+        # a row's sample is a real next token only once its chunk reaches
+        # the final prompt token (decode rows always; prefilling rows
+        # exactly on the step their prompt completes)
+        samples = (lengths + n_new) >= prompt_len
 
     # (6) fused OA validation: one pass over page_version for all C tokens.
     # Speculative rows advance by the ACCEPTED prefix only — the rejected
@@ -427,27 +445,30 @@ def fused_decode_step(params, kv, pool, block_tables, snapshot, lengths,
     # steps where no reclamation can have invalidated a snapshot.  Grant
     # starvation is checked unconditionally — it is an allocation outcome,
     # not a reclamation hazard.
-    if do_validate is None:
-        do_val = jnp.asarray(True)
-    else:
-        do_val = jnp.asarray(do_validate, bool)
-    valid_oa = jax.lax.cond(
-        do_val,
-        lambda: pp._validate_and_commit_impl(pool, block_tables, snapshot)[0],
-        lambda: jnp.ones((B,), bool))
-    valid = valid_oa & active & grant_ok
-    adv = jnp.where(valid, commit_n, 0).astype(jnp.int32)
-    lengths = lengths + adv
-    last_tok = jnp.where(valid & samples, nxt, last_tok)
-    if mesh is not None and mesh.shape.get("model", 1) > 1:
-        # pin the TP layout on the way out: head-sharded arena, replicated
-        # everything-else — by construction every shard ran the identical
-        # pool/validation math, so the replicated outputs agree bit-for-bit
-        # and the engine's single device_get pulls ONE host-visible result
-        kv, rest = _tp_pin(
-            mesh, kv, (pool, block_tables, snapshot, lengths, last_tok,
-                       nxt, valid, grant_info, cow, adv, n_acc))
-        (pool, block_tables, snapshot, lengths, last_tok,
-         nxt, valid, grant_info, cow, adv, n_acc) = rest
+    with jax.named_scope("step.validate"):
+        if do_validate is None:
+            do_val = jnp.asarray(True)
+        else:
+            do_val = jnp.asarray(do_validate, bool)
+        valid_oa = jax.lax.cond(
+            do_val,
+            lambda: pp._validate_and_commit_impl(pool, block_tables,
+                                                 snapshot)[0],
+            lambda: jnp.ones((B,), bool))
+        valid = valid_oa & active & grant_ok
+        adv = jnp.where(valid, commit_n, 0).astype(jnp.int32)
+        lengths = lengths + adv
+        last_tok = jnp.where(valid & samples, nxt, last_tok)
+        if mesh is not None and mesh.shape.get("model", 1) > 1:
+            # pin the TP layout on the way out: head-sharded arena,
+            # replicated everything-else — by construction every shard ran
+            # the identical pool/validation math, so the replicated outputs
+            # agree bit-for-bit and the engine's single device_get pulls ONE
+            # host-visible result
+            kv, rest = _tp_pin(
+                mesh, kv, (pool, block_tables, snapshot, lengths, last_tok,
+                           nxt, valid, grant_info, cow, adv, n_acc))
+            (pool, block_tables, snapshot, lengths, last_tok,
+             nxt, valid, grant_info, cow, adv, n_acc) = rest
     return (kv, pool, block_tables, snapshot, lengths, last_tok,
             nxt, valid, grant_info, cow, adv, n_acc)

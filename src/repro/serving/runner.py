@@ -27,6 +27,7 @@ import numpy as np
 from repro.kernels.ops import resolve_attn_impl
 from .kv_manager import DeviceStepState, KVCacheManager
 from .paged_decode import fused_decode_step
+from .spans import RUNNER_COLLECT, RUNNER_LAUNCH, span
 
 
 class StepResult(NamedTuple):
@@ -111,43 +112,46 @@ class ModelRunner:
         (``Scheduler.plan_validate``): False elides the fused OA
         validation pass via a resident device boolean — no recompile, no
         transfer, same executable."""
-        self._step_idx += 1
-        # greedy decode never consumes the key — skip the fold_in dispatches
-        key = (self._base_key if self.greedy
-               else jax.random.fold_in(self._base_key, self._step_idx))
-        st = kvm.step_state()
-        speculative = drafts is not None
-        if speculative:
-            B = kvm.max_batch
-            dt = np.zeros((B, max(chunk_size - 1, 1)), np.int32)
-            dl = np.zeros((B,), np.int32)
-            for slot, toks in drafts.items():
-                dl[slot] = len(toks)
-                dt[slot, :len(toks)] = toks
-            draft_args = (jnp.asarray(dt), jnp.asarray(dl))
-        else:
-            draft_args = (None, None)
-        (kv, pool, bt, snap, lengths, last,
-         nxt, valid, grant_info, cow, adv, n_acc) = fused_decode_step(
-            self.params, st.kv, st.pool, st.block_tables, st.snapshot,
-            st.lengths, st.last_tok, st.active, st.prompt_buf, st.prompt_len,
-            key, self._temperature,
-            (self._budget_one if chunk_size == 1
-             else jnp.asarray(budget, jnp.int32)),
-            draft_args[0], draft_args[1],
-            self._val_true if do_validate else self._val_false,
-            cfg=self.cfg, impl=self.attn_impl, greedy=self.greedy,
-            pages_per_compute_block=self.pages_per_compute_block,
-            chunk_size=chunk_size, speculative=speculative, mesh=self.mesh)
-        kvm.install_state(DeviceStepState(
-            kv, pool, bt, snap, lengths, last,
-            st.active, st.prompt_buf, st.prompt_len))
+        with span(RUNNER_LAUNCH):
+            self._step_idx += 1
+            # greedy decode never consumes the key: skip the fold_in
+            # dispatches
+            key = (self._base_key if self.greedy
+                   else jax.random.fold_in(self._base_key, self._step_idx))
+            st = kvm.step_state()
+            speculative = drafts is not None
+            if speculative:
+                B = kvm.max_batch
+                dt = np.zeros((B, max(chunk_size - 1, 1)), np.int32)
+                dl = np.zeros((B,), np.int32)
+                for slot, toks in drafts.items():
+                    dl[slot] = len(toks)
+                    dt[slot, :len(toks)] = toks
+                draft_args = (jnp.asarray(dt), jnp.asarray(dl))
+            else:
+                draft_args = (None, None)
+            (kv, pool, bt, snap, lengths, last,
+             nxt, valid, grant_info, cow, adv, n_acc) = fused_decode_step(
+                self.params, st.kv, st.pool, st.block_tables, st.snapshot,
+                st.lengths, st.last_tok, st.active, st.prompt_buf,
+                st.prompt_len, key, self._temperature,
+                (self._budget_one if chunk_size == 1
+                 else jnp.asarray(budget, jnp.int32)),
+                draft_args[0], draft_args[1],
+                self._val_true if do_validate else self._val_false,
+                cfg=self.cfg, impl=self.attn_impl, greedy=self.greedy,
+                pages_per_compute_block=self.pages_per_compute_block,
+                chunk_size=chunk_size, speculative=speculative, mesh=self.mesh)
+            kvm.install_state(DeviceStepState(
+                kv, pool, bt, snap, lengths, last,
+                st.active, st.prompt_buf, st.prompt_len))
         return (nxt, valid, grant_info, cow, adv, n_acc)
 
     def collect(self, pending) -> StepResult:
         """THE one host transfer of a steady-state step: materialise the
         six per-slot arrays in a single ``device_get``."""
-        return StepResult(*jax.device_get(pending))
+        with span(RUNNER_COLLECT):
+            return StepResult(*jax.device_get(pending))
 
     def execute(self, kvm: KVCacheManager, *, chunk_size: int = 1,
                 budget: int = 1, drafts: dict | None = None,

@@ -21,6 +21,7 @@ routes between pools with the same pressure arithmetic this module exposes
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import time
 
@@ -32,7 +33,20 @@ from .draft import NGramDrafter
 from .kv_manager import KVCacheManager
 from .overload import (DEFAULT_CLASSES, ClassQueues, DegradationLadder,
                        LadderConfig, VICTIM_POLICIES)
+from .spans import (REQ_ADMIT, REQ_SUBMIT, SCHED_ABSORB, SCHED_ADMIT,
+                    SCHED_MAINTAIN, SCHED_PLAN, no_span)
 from .stats import EngineStats
+
+
+def _spanned(name: str):
+    """Run the method inside the scheduler's span ``name``."""
+    def wrap(method):
+        @functools.wraps(method)
+        def inner(self, *args, **kwargs):
+            with self.span(name):
+                return method(self, *args, **kwargs)
+        return inner
+    return wrap
 
 
 def required_pages_per_seq(prompt_len: int, max_new: int,
@@ -79,7 +93,6 @@ class Request:
     admitted_step: int | None = None  # engine step count at FIRST admission
     first_token_at: float | None = None  # clock at first generated token
     first_token_step: int | None = None  # engine step that produced it
-    _last_token_t: float | None = None  # clock at last token (ITL stream)
     slot: int | None = None  # batch row while running
     pages_held: int = 0  # host-side page COUNT (ids live on device)
     externally_reclaimed: bool = False  # a reclaimer raced us and owns the pages
@@ -337,13 +350,17 @@ class Scheduler:
                  max_queue_depth: int | None = None,
                  victim_policy="youngest",
                  ladder: DegradationLadder | LadderConfig | bool | None = None,
-                 clock=None):
+                 clock=None, span=None):
         self.kvm = kvm
         self.stats = stats
         # the scheduler's one clock: monotonic by default (deadlines and
         # speed samples must not jump with NTP/wall adjustments); injectable
         # for deterministic tests
         self.clock = clock if clock is not None else time.monotonic
+        # host spans (serving/spans.py): ``span(name, **ids)`` is a context
+        # manager; the engine passes the profiler's, the default records
+        # nothing
+        self.span = span if span is not None else no_span
         self.num_pages = num_pages
         self.page_size = page_size
         self.max_batch = max_batch
@@ -449,6 +466,15 @@ class Scheduler:
     def submit(self, prompt: list[int], max_new_tokens: int,
                deadline: float | None = None,
                cls: str = "interactive") -> Request:
+        """Queue a request (:meth:`_submit`) inside its ``req.submit``
+        span, which carries the request's ``rid``."""
+        with self.span(REQ_SUBMIT) as sp:
+            req = self._submit(prompt, max_new_tokens, deadline, cls)
+            sp.set_metadata(rid=req.rid)
+        return req
+
+    def _submit(self, prompt: list[int], max_new_tokens: int,
+                deadline: float | None, cls: str) -> Request:
         """Queue a request (host-only; no device work until admission).
 
         Degenerate inputs — an empty prompt, a non-positive or non-int
@@ -593,114 +619,124 @@ class Scheduler:
 
     # -- admission -----------------------------------------------------------
 
+    @_spanned(SCHED_ADMIT)
     def admit(self) -> None:
         """Admission (an allowed sync point): match the prefix index, grant
         shared pages, reserve the first step's worst-case page demand
         against the starvation guard, allocate the fresh page (remap →
         evict → preempt on exhaustion) and install the slot."""
-        ps = self.page_size
         while self.queue and len(self.running) < self.max_batch:
             req = self.queue[0]
             if self._shed_if_hopeless(req):
                 continue  # SLO policy dropped it; try the next in line
-            need_total = (req.target_len + ps - 1) // ps
-            if need_total > min(self.num_pages, self.kvm.max_pages_per_seq):
-                raise MemoryError(
-                    f"request {req.rid} needs {need_total} pages; the pool "
-                    f"can never satisfy it (num_pages={self.num_pages})")
-            if self.prefix_cache:
-                m, chain, tail_page = self.index.match(req.prompt)
-            else:
-                m, chain, tail_page = 0, [], -1
-            shared = chain + ([tail_page] if tail_page >= 0 else [])
-            # share BEFORE the alloc loop: the sharer mirror marks these
-            # pages so pressure eviction inside the loop cannot free them
-            if shared:
-                self.kvm.share(shared)
-            need_fresh = (m % ps == 0)  # first write lands on a new page
-            fresh_page = -1
-            # Starvation guard — for EVERY admission: running rows that need
-            # pages THIS step have first claim on the free pool; this
-            # admission reserves the fresh page plus every page its FIRST
-            # step will demand (a chunk can straddle several, a tail match
-            # COWs).  Host arithmetic over the mirrors only.
-            used = self.distinct_pages_in_use()
-            need_now = sum(self.pages_needed_next_step(r)
-                           for r in self.running)
-            n_first = min(max(1, self._chunk_cap()),
-                          len(req.prompt) - m)
-            held_after = len(shared) + (1 if need_fresh else 0)
-            first_need = max(0, (m + n_first - 1) // ps + 1 - held_after)
-            if tail_page >= 0:
-                first_need += 1  # the first step COWs the shared tail page
-            reserve = (1 if need_fresh else 0) + first_need
-            short = reserve + used + need_now - self.kvm.mapped_pages
-            if short > 0:
-                self.kvm.remap_for(short)
-                short = (reserve + self.distinct_pages_in_use() + need_now
-                         - self.kvm.mapped_pages)
-                if short > 0 and self.prefix_cache:
-                    # cache-only pages cost no running request anything:
-                    # evict them before refusing admission
-                    self.index.evict(short)
-                    short = (reserve + self.distinct_pages_in_use()
-                             + need_now - self.kvm.mapped_pages)
-                if short > 0:
-                    self._unshare_admission(shared)
-                    break  # remap + eviction fell short: a partial cover
-                    # must not let admission steal a starved row's page
-            if need_fresh:
-                denials = 0
-                while True:
-                    fresh_page = self.kvm.alloc_fresh()
-                    if fresh_page is not None:
-                        break
-                    self.stats.record_grant_denial()
-                    denials += 1
-                    if denials <= self.grant_retry_limit:
-                        # bounded plain retry: a transient denial (chaos
-                        # fault, or a concurrent release racing the alloc)
-                        # should not immediately cost an eviction or victim
-                        self.stats.record_grant_retry()
-                        continue
-                    # released memory covers the need? remap, then evict the
-                    # prefix cache, and only then preempt a running request
-                    if self.kvm.remap_for(1):
-                        continue
-                    if self.prefix_cache and self.index.evict(1) > 0:
-                        continue
-                    if self.policy.pending_frees():
-                        # deferred frees (interval limbo) mature within the
-                        # lag; a preemption now would only add to the limbo
-                        # without making a single page grantable — wait
-                        self._unshare_admission(shared)
-                        return
-                    victim = self.pick_victim(exclude=req)
-                    if victim is None:
-                        self._unshare_admission(shared)
-                        return  # req waits for memory
-                    self.preempt(victim)  # free pages, then retry the alloc
-            slot = self.kvm.free_slot_index()
-            row = shared + ([fresh_page] if need_fresh else [])
-            self.kvm.install_slot(slot, row, m, req.prompt)
-            self.queue.popleft()
-            req.state = "running"
-            req.slot = slot
-            if req.admitted_step is None:  # restarts keep the original clock
-                req.admitted_step = self.stats.steps
-            req.committed = m
-            req.prefix_reused = m
-            req.shared_chain = dict(enumerate(shared))
-            req.shared_held = len(shared)
-            req.pages_held = len(shared) + (1 if need_fresh else 0)
-            self.kvm.slots[slot] = req
-            self.running.append(req)
-            if need_fresh:
-                self.stats.record_grants(1)
-            if m > 0:
-                self.stats.record_prefix_hit(m)
-            # a preemption above may have requeued the victim behind req;
+            with self.span(REQ_ADMIT, rid=req.rid) as sp:
+                if not self._admit_head(req):
+                    return  # the head waits for memory: so does the queue
+                sp.set_metadata(slot=req.slot, prefix=req.prefix_reused)
+            # a preemption inside may have requeued the victim behind req;
             # keep admitting — the loop condition re-checks capacity
+
+    def _admit_head(self, req: Request) -> bool:
+        """Admit the queue head ``req`` (:meth:`admit`); False, with every
+        share it took undone, when memory cannot cover it yet."""
+        ps = self.page_size
+        need_total = (req.target_len + ps - 1) // ps
+        if need_total > min(self.num_pages, self.kvm.max_pages_per_seq):
+            raise MemoryError(
+                f"request {req.rid} needs {need_total} pages; the pool "
+                f"can never satisfy it (num_pages={self.num_pages})")
+        if self.prefix_cache:
+            m, chain, tail_page = self.index.match(req.prompt)
+        else:
+            m, chain, tail_page = 0, [], -1
+        shared = chain + ([tail_page] if tail_page >= 0 else [])
+        # share BEFORE the alloc loop: the sharer mirror marks these
+        # pages so pressure eviction inside the loop cannot free them
+        if shared:
+            self.kvm.share(shared)
+        need_fresh = (m % ps == 0)  # first write lands on a new page
+        fresh_page = -1
+        # Starvation guard — for EVERY admission: running rows that need
+        # pages THIS step have first claim on the free pool; this
+        # admission reserves the fresh page plus every page its FIRST
+        # step will demand (a chunk can straddle several, a tail match
+        # COWs).  Host arithmetic over the mirrors only.
+        used = self.distinct_pages_in_use()
+        need_now = sum(self.pages_needed_next_step(r)
+                       for r in self.running)
+        n_first = min(max(1, self._chunk_cap()),
+                      len(req.prompt) - m)
+        held_after = len(shared) + (1 if need_fresh else 0)
+        first_need = max(0, (m + n_first - 1) // ps + 1 - held_after)
+        if tail_page >= 0:
+            first_need += 1  # the first step COWs the shared tail page
+        reserve = (1 if need_fresh else 0) + first_need
+        short = reserve + used + need_now - self.kvm.mapped_pages
+        if short > 0:
+            self.kvm.remap_for(short)
+            short = (reserve + self.distinct_pages_in_use() + need_now
+                     - self.kvm.mapped_pages)
+            if short > 0 and self.prefix_cache:
+                # cache-only pages cost no running request anything:
+                # evict them before refusing admission
+                self.index.evict(short)
+                short = (reserve + self.distinct_pages_in_use()
+                         + need_now - self.kvm.mapped_pages)
+            if short > 0:
+                self._unshare_admission(shared)
+                return False  # remap + eviction fell short: a partial cover
+                # must not let admission steal a starved row's page
+        if need_fresh:
+            denials = 0
+            while True:
+                fresh_page = self.kvm.alloc_fresh()
+                if fresh_page is not None:
+                    break
+                self.stats.record_grant_denial()
+                denials += 1
+                if denials <= self.grant_retry_limit:
+                    # bounded plain retry: a transient denial (chaos
+                    # fault, or a concurrent release racing the alloc)
+                    # should not immediately cost an eviction or victim
+                    self.stats.record_grant_retry()
+                    continue
+                # released memory covers the need? remap, then evict the
+                # prefix cache, and only then preempt a running request
+                if self.kvm.remap_for(1):
+                    continue
+                if self.prefix_cache and self.index.evict(1) > 0:
+                    continue
+                if self.policy.pending_frees():
+                    # deferred frees (interval limbo) mature within the
+                    # lag; a preemption now would only add to the limbo
+                    # without making a single page grantable — wait
+                    self._unshare_admission(shared)
+                    return False
+                victim = self.pick_victim(exclude=req)
+                if victim is None:
+                    self._unshare_admission(shared)
+                    return False  # req waits for memory
+                self.preempt(victim)  # free pages, then retry the alloc
+        slot = self.kvm.free_slot_index()
+        row = shared + ([fresh_page] if need_fresh else [])
+        self.kvm.install_slot(slot, row, m, req.prompt)
+        self.queue.popleft()
+        req.state = "running"
+        req.slot = slot
+        if req.admitted_step is None:  # restarts keep the original clock
+            req.admitted_step = self.stats.steps
+        req.committed = m
+        req.prefix_reused = m
+        req.shared_chain = dict(enumerate(shared))
+        req.shared_held = len(shared)
+        req.pages_held = len(shared) + (1 if need_fresh else 0)
+        self.kvm.slots[slot] = req
+        self.running.append(req)
+        if need_fresh:
+            self.stats.record_grants(1)
+        if m > 0:
+            self.stats.record_prefix_hit(m)
+        return True
 
     def _shed_if_hopeless(self, req: Request) -> bool:
         """SLO admission control: drop ``req`` (state ``"shed"``) iff its
@@ -840,6 +876,7 @@ class Scheduler:
             return 1
         return 0
 
+    @_spanned(SCHED_PLAN)
     def plan_validate(self) -> bool:
         """Ask the reclamation policy whether THIS step's fused dispatch
         must run the OA validation pass (host mirrors only — the clock
@@ -853,6 +890,7 @@ class Scheduler:
             self._planned_clock)
         return self._step_validates
 
+    @_spanned(SCHED_PLAN)
     def plan_chunk(self) -> tuple[int, int, dict | None]:
         """Pick the executable (C), the traced budget and the draft plan for
         this step from host mirrors only.  C=1 is classic decode;
@@ -920,6 +958,7 @@ class Scheduler:
             cap = min(cap, self._ladder_chunk_cap)
         return cap
 
+    @_spanned(SCHED_ABSORB)
     def absorb(self, res, C: int, budget: int,
                inject_preemption_of: Request | None = None,
                drafts: dict | None = None) -> None:
@@ -967,7 +1006,6 @@ class Scheduler:
 
         starved: list[Request] = []
         step_drafted = step_accepted = 0
-        step_t = self.clock()  # one host clock read serves every row's ITL
         for req in list(self.running):
             if req.state != "running":
                 continue  # preempted mid-flight; its row is dead anyway
@@ -1007,18 +1045,10 @@ class Scheduler:
                     step_accepted += acc
                     self.stats.record_speculation(len(row_drafts), acc)
                     req.generated.extend(row_drafts[:acc] + [int(tok_np[i])])
-                    n_new = acc + 1
                 else:
                     req.generated.append(int(tok_np[i]))
-                    n_new = 1
                 if req.first_token_step is None:
                     self._record_ttft(req)
-                elif req._last_token_t is not None:
-                    # streaming inter-token latency: this step's wall gap
-                    # amortised over the tokens the row committed
-                    self.stats.record_itl(
-                        req.cls, (step_t - req._last_token_t) / n_new)
-                req._last_token_t = step_t
             if len(req.generated) >= req.max_new_tokens:
                 req.state = "finished"
                 self.stats.record_class_finish(req.cls)
@@ -1158,6 +1188,7 @@ class Scheduler:
         return max(self._adaptive_floor,
                    int(self._gap_ewma * 1.5 + 0.999))
 
+    @_spanned(SCHED_MAINTAIN)
     def maintain(self) -> None:
         """Quiescence-driven release tick: after ``_release_threshold()``
         pressure-free ticks, release capacity no running request can demand

@@ -72,9 +72,9 @@ class LatencyReservoir:
 
 @dataclasses.dataclass
 class ClassStats:
-    """Per-request-class accounting: lifecycle counters plus streaming
-    TTFT and inter-token-latency reservoirs (host-only — nothing here
-    touches the device or adds a sync)."""
+    """Per-request-class accounting: lifecycle counters plus a streaming
+    TTFT reservoir (host-only — nothing here touches the device or adds a
+    sync)."""
 
     name: str
     submitted: int = 0
@@ -83,18 +83,13 @@ class ClassStats:
     rejected: int = 0
     ttft: LatencyReservoir = dataclasses.field(
         default_factory=LatencyReservoir)
-    itl: LatencyReservoir = dataclasses.field(
-        default_factory=LatencyReservoir)
 
     def percentiles(self) -> dict[str, float]:
-        """p50/p95/p99 of both reservoirs (0.0 for a quiet class)."""
+        """p50/p95/p99 of the TTFT reservoir (0.0 for a quiet class)."""
         return {
             "ttft_p50": self.ttft.percentile(50),
             "ttft_p95": self.ttft.percentile(95),
             "ttft_p99": self.ttft.percentile(99),
-            "itl_p50": self.itl.percentile(50),
-            "itl_p95": self.itl.percentile(95),
-            "itl_p99": self.itl.percentile(99),
         }
 
     def summary(self) -> dict:
@@ -218,10 +213,6 @@ class EngineStats:
             (seconds - self.mean_ttft_seconds) / self.ttft_requests)
         if cls is not None:
             self._class(cls).ttft.add(seconds)
-
-    def record_itl(self, cls: str, seconds: float) -> None:
-        """One inter-token gap observed for a running request of ``cls``."""
-        self._class(cls).itl.add(seconds)
 
     def record_class_submit(self, cls: str) -> None:
         """A request of ``cls`` was accepted into the admission queue."""
@@ -433,7 +424,6 @@ def aggregate_stats(parts: list[EngineStats],
             tc.shed += cs.shed
             tc.rejected += cs.rejected
             tc.ttft.merge_from(cs.ttft)
-            tc.itl.merge_from(cs.itl)
         if s.ttft_requests:
             n = total.ttft_requests + s.ttft_requests
             total.mean_ttft_steps += (

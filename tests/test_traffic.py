@@ -377,7 +377,6 @@ def test_class_stats_aggregate_across_replicas():
     a.record_rejection("interactive")
     a.record_ladder(1)
     b.record_ttft(5, 0.3, cls="interactive")
-    b.record_itl("interactive", 0.01)
     b.record_shed(cls="background", by_ladder=True)
     tot = aggregate_stats([a, b])
     cs = tot.class_stats["interactive"]
