@@ -131,10 +131,17 @@ def _chunk_core(params, kv, block_tables, lengths, tokens, n_new, *, cfg,
     updated kv).  ``write_ok`` [B] bool masks ALL of a row's appends (the
     starved-grant case: a denied row must not touch the shared page it
     failed to diverge from).
+
+    The layer loop carries the arena whole, flattened to [L*P, page, Hkv, D]
+    (merging the two leading axes is a bitcast): layer l's page p is row
+    ``l*P + p``, so each layer offsets its page ids instead of slicing its
+    [P, page, Hkv, D] out.  As carry it is updated in place; scanned as
+    input and output it would be sliced out, restacked and copied back
+    every step, three arena-sized copies each of K and V.
     """
     assert cfg.family in ("dense", "moe", "vlm"), "paged decode: decoder LMs only"
     B, C = tokens.shape
-    page_size = kv["k"].shape[2]
+    L, P, page_size = kv["k"].shape[:3]
     M = block_tables.shape[1]
     positions = lengths[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
     x = embed_tokens(cfg, params["embed"], tokens, positions)
@@ -143,7 +150,9 @@ def _chunk_core(params, kv, block_tables, lengths, tokens, n_new, *, cfg,
     slot = positions % page_size
     pages = jnp.take_along_axis(
         block_tables, jnp.minimum(pos_page, M - 1), axis=1)  # [B, C]
-    drop = kv["k"].shape[1]  # OOB page id -> dropped write
+    # OOB row of the WHOLE flattened arena -> dropped write (``off + P``
+    # would be the next layer's page 0)
+    drop = L * P
     wvalid = (jnp.arange(C)[None, :] < n_new[:, None]) & (pages >= 0) \
         & (pos_page < M)
     if write_ok is not None:
@@ -152,19 +161,25 @@ def _chunk_core(params, kv, block_tables, lengths, tokens, n_new, *, cfg,
         # an in-place write there would corrupt every other holder's KV
         # without any version bump to warn them
         wvalid = wvalid & write_ok[:, None]
-    pidx = jnp.where(wvalid, pages, drop)
     total_len = lengths + n_new
+    k_all = kv["k"].reshape(L * P, *kv["k"].shape[2:])
+    v_all = kv["v"].reshape(L * P, *kv["v"].shape[2:])
 
-    def layer(x, scanned):
-        blk, kl, vl = scanned  # kl/vl [P, page, Hkv, D]
+    def layer(carry, scanned):
+        x, k_all, v_all = carry  # k_all/v_all [L*P, page, Hkv, D]
+        blk, l = scanned
+        off = l * P
         with jax.named_scope("layer.kv_write"):
             h = apply_norm(cfg, x, blk["ln1"])
             q, k, v = attention_qkv(cfg, h, blk["attn"], positions)
-            kl = kl.at[pidx, slot].set(k, mode="drop")
-            vl = vl.at[pidx, slot].set(v, mode="drop")
+            pidx = jnp.where(wvalid, pages + off, drop)
+            k_all = k_all.at[pidx, slot].set(k, mode="drop")
+            v_all = v_all.at[pidx, slot].set(v, mode="drop")
         with jax.named_scope("layer.attention"):
+            # unmapped entries stay -1 (never read past the mask)
+            bt = jnp.where(block_tables >= 0, block_tables + off, -1)
             att = paged_attention(
-                q, {"k": kl, "v": vl}, block_tables, total_len, impl=impl,
+                q, {"k": k_all, "v": v_all}, bt, total_len, impl=impl,
                 pages_per_compute_block=pages_per_compute_block,
                 chunk_lens=n_new, mesh=mesh)
             x = x + att.reshape(B, C, -1) @ blk["attn"]["wo"]
@@ -178,11 +193,14 @@ def _chunk_core(params, kv, block_tables, lengths, tokens, n_new, *, cfg,
                 y, _ = moe_apply(cfg, h2, blk["moe"], dropless=True)
             else:
                 y = mlp_apply(cfg, h2, blk["mlp"])
-            return x + y, (kl, vl)
+            return (x + y, k_all, v_all), None
 
-    x, (ks, vs) = jax.lax.scan(layer, x, (params["blocks"], kv["k"], kv["v"]))
+    (x, k_all, v_all), _ = jax.lax.scan(
+        layer, (x, k_all, v_all),
+        (params["blocks"], jnp.arange(L, dtype=jnp.int32)))
     x = apply_norm(cfg, x, params["final_norm"])
-    return x, {"k": ks, "v": vs}
+    return x, {"k": k_all.reshape(kv["k"].shape),
+               "v": v_all.reshape(kv["v"].shape)}
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "impl"), donate_argnums=(1,))

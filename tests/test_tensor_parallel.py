@@ -16,6 +16,7 @@ What the parity subprocess pins down (the tentpole's correctness claim):
   shared metadata vs per-shard payloads.
 """
 
+import functools
 import json
 import os
 import subprocess
@@ -163,11 +164,13 @@ _PARITY_PROG = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import json
+import re
 import jax
 import numpy as np
 from repro.configs import get_config, reduced
 from repro.models import build_model
 from repro.serving import PagedServingEngine
+from repro.serving.paged_decode import fused_decode_step
 
 CFG = reduced(get_config("olmo-1b"))
 params = build_model(CFG).init(jax.random.PRNGKey(0))
@@ -185,6 +188,7 @@ def run(tp):
     eng = PagedServingEngine(CFG, params, num_pages=64, page_size=2,
                              max_batch=4, prefix_cache=True, speculative_k=2,
                              prefill_chunk=4, tensor_parallel=tp)
+    kv_in = eng.kv_manager.step_state().kv["k"].sharding
     reqs = [eng.submit(p, 8) for p in PROMPTS]
     eng.run()
     # second wave AFTER the first drained: its donated prefixes are now in
@@ -198,11 +202,22 @@ def run(tp):
             np.asarray(st.kv["k"], dtype=np.float32),
             np.asarray(st.kv["v"], dtype=np.float32),
             np.asarray(st.lengths), np.asarray(st.block_tables),
-            dev_bytes(st.kv), dev_bytes(eng.params), eng, st)
+            dev_bytes(st.kv), dev_bytes(eng.params), eng, st,
+            all(a.sharding.is_equivalent_to(kv_in, a.ndim)
+                for a in st.kv.values()))
 
 
-t1, k1, v1, len1, bt1, kvb1, pb1, e1, st1 = run(1)
-t2, k2, v2, len2, bt2, kvb2, pb2, e2, st2 = run(2)
+t1, k1, v1, len1, bt1, kvb1, pb1, e1, st1, kept1 = run(1)
+t2, k2, v2, len2, bt2, kvb2, pb2, e2, st2, kept2 = run(2)
+# the TP=2 chunk step as compiled: the head-sharded arena needs no
+# collective that moves it, only the all-reduces after wo and the MLP
+r2 = e2.runner
+hlo = fused_decode_step.lower(
+    e2.params, st2.kv, st2.pool, st2.block_tables, st2.snapshot,
+    st2.lengths, st2.last_tok, st2.active, st2.prompt_buf, st2.prompt_len,
+    r2._base_key, r2._temperature, r2._budget_one, None, None,
+    r2._val_true, cfg=CFG, impl=r2.attn_impl, chunk_size=4,
+    mesh=e2.mesh).compile().as_text()
 out = {
     "tokens_equal": t1 == t2,
     "k_allclose": bool(np.allclose(k1, k2, atol=2e-2, rtol=2e-2)),
@@ -212,6 +227,9 @@ out = {
     "kv_bytes_tp1": kvb1, "kv_bytes_tp2": kvb2,
     "param_bytes_tp1": pb1, "param_bytes_tp2": pb2,
     "kv_spec_tp2": str(st2.kv["k"].sharding.spec),
+    "kv_sharding_kept": kept1 and kept2,
+    "arena_moves": re.findall(r"(all-gather|all-to-all|collective-permute)\(",
+                              hlo),
     "pool_replicated": len(e2.pool.clock.sharding.device_set) == 2
                        and str(e2.pool.clock.sharding.spec)
                        == "PartitionSpec()",
@@ -222,12 +240,17 @@ print(json.dumps(out))
 """
 
 
+@functools.cache
+def _parity() -> dict:
+    return _run_subprocess(_PARITY_PROG)
+
+
 def test_tp2_matches_tp1_token_exact():
     """TP=2 must be a pure layout change: same greedy tokens, same KV arena
     contents (bf16 tolerance for psum reassociation), same lengths and block
     tables, on a workload exercising chunked prefill + speculative decoding
     + prefix sharing simultaneously."""
-    out = _run_subprocess(_PARITY_PROG)
+    out = _parity()
     assert out["tokens_equal"], out
     assert out["k_allclose"] and out["v_allclose"], out
     assert out["lengths_equal"] and out["tables_equal"], out
@@ -239,11 +262,21 @@ def test_tp2_shards_kv_and_weights():
     """Per-device KV bytes halve EXACTLY at TP=2 (head axis shards, page and
     slot axes never do) and per-device weight bytes shrink; pool metadata
     (the OA clock) stays replicated across both shard devices."""
-    out = _run_subprocess(_PARITY_PROG)
+    out = _parity()
     assert out["kv_bytes_tp2"] * 2 == out["kv_bytes_tp1"], out
     assert out["param_bytes_tp2"] < out["param_bytes_tp1"], out
     assert "model" in out["kv_spec_tp2"], out
     assert out["pool_replicated"], out
+
+
+def test_tp2_step_keeps_arena_sharding():
+    """The fused step hands the arena back in the layout it took (TP=1 and
+    TP=2), and the layer loop's flattened [L*P, page, Hkv, D] view stays
+    head-sharded inside it: the compiled TP=2 step gathers and permutes
+    nothing."""
+    out = _parity()
+    assert out["kv_sharding_kept"], out
+    assert out["arena_moves"] == [], out
 
 
 # ------------------------------------------------------------ 2D fleet

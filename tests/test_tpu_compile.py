@@ -6,13 +6,27 @@ is described, not attached, and refuses what the chip would refuse — Mosaic
 layouts it cannot lower, more scoped VMEM than the kernel may use.  Each
 case is an executable shape the serving engine builds: decode (C=1), chunked
 prefill (C=16), a speculative chunk (K=8 drafts, C=9) and a compute block of
-two pages.  The topology is described inside a fixture, never at import:
-only one process at a time may load the TPU library.
+two pages.
+
+The fused serving step, compiled the same way with a small depth and arena
+(4 layers, 512 pages), must keep the KV arena whole through the layer loop.
+Held as the loop's carry, the arena is updated in place; scanned as the
+loop's input and output, it would be sliced out a layer at a time,
+restacked, and copied whole into the donated output, every step.  The guard
+reads that off the compiled program: its temporaries stay below one layer's
+K slice, and no ``copy`` or ``dynamic-slice`` produces a buffer of the
+arena's shape or of one layer's slice.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, so these compiles stay in this
+one file.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -36,8 +50,13 @@ def topo():
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        yield topologies.get_topology_desc(platform="tpu",
-                                           topology_name="v5e:2x2")
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    try:
+        yield topo
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
 
@@ -70,3 +89,55 @@ def test_paged_attention_compiles_for_v5e(one_chip, chunk, ppcb):
         sds((BATCH, MAX_PAGES), jnp.int32), sds((BATCH,), jnp.int32),
         chunk_lens).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+LAYERS, ARENA_PAGES, PROMPT_CAP = 4, 512, 64
+
+
+def _big_copies(hlo: str, shapes) -> list[str]:
+    """Instructions of ``hlo`` that are a ``copy`` or ``dynamic-slice`` with a
+    bf16 result of one of ``shapes``."""
+    dims = "|".join(",".join(map(str, s)) for s in shapes)
+    pat = re.compile(r"=\s*bf16\[(?:" + dims + r")\]\S*\s+"
+                     r"(?:copy|dynamic-slice)\(")
+    return [ln.strip() for ln in hlo.splitlines() if pat.search(ln)]
+
+
+@pytest.mark.parametrize("chunk", [1, 16], ids=["decode", "chunk16"])
+def test_fused_step_keeps_arena_whole(one_chip, chunk):
+    from repro.configs import get_config
+    from repro.core.pagepool import pool_init
+    from repro.models import build_model
+    from repro.serving.paged_decode import fused_decode_step
+
+    cfg = dataclasses.replace(get_config("olmo-1b"), n_layers=LAYERS)
+    hkv, hd = cfg.n_kv_heads, cfg.head_dim
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        build_model(cfg).init, jax.random.PRNGKey(0)))
+    arena = (LAYERS, ARENA_PAGES, PAGE, hkv, hd)
+    kv = sds(arena, jnp.bfloat16)
+    pool = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                        jax.eval_shape(lambda: pool_init(ARENA_PAGES)))
+    i32 = jnp.int32
+    compiled = fused_decode_step.lower(
+        params, {"k": kv, "v": kv}, pool, sds((BATCH, MAX_PAGES), i32),
+        sds((BATCH, MAX_PAGES), jnp.uint32), sds((BATCH,), i32),
+        sds((BATCH,), i32), sds((BATCH,), bool),
+        sds((BATCH, PROMPT_CAP), i32), sds((BATCH,), i32),
+        sds((2,), jnp.uint32), sds((), jnp.float32), sds((), i32), None,
+        None, sds((), bool), cfg=cfg, impl="pallas", greedy=True,
+        chunk_size=chunk).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+
+    layer_slice = (ARENA_PAGES, PAGE, hkv, hd)
+    slice_bytes = ARENA_PAGES * PAGE * hkv * hd * 2  # one layer's K: 32 MiB
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < slice_bytes, f"temporaries {temp / 2**20:.1f} MiB"
+    big = _big_copies(hlo, [arena, (LAYERS * ARENA_PAGES, PAGE, hkv, hd),
+                            layer_slice, (1, *layer_slice)])
+    assert not big, "\n".join(big[:6])
