@@ -37,6 +37,7 @@ class ArchConfig:
     sliding_window: int | None = None
     use_rope: bool = True
     rope_theta: float = 1e4
+    rotary_pct: float = 1.0  # share of each head that RoPE rotates
     attn_chunk: int = 512  # flash KV-chunk size
     # norm / mlp
     norm_type: str = "rmsnorm"  # rmsnorm | layernorm | nonparam_ln
@@ -73,6 +74,16 @@ class ArchConfig:
             object.__setattr__(self, "vocab_padded", _pad128(self.vocab))
         if not self.rnn_width:
             object.__setattr__(self, "rnn_width", self.d_model)
+        if self.use_rope and (self.rope_dim <= 0 or self.rope_dim % 2):
+            raise ValueError(f"rotary_pct {self.rotary_pct} of head_dim "
+                             f"{self.head_dim} rotates {self.rope_dim} dims; "
+                             f"RoPE needs an even, positive number")
+
+    @property
+    def rope_dim(self) -> int:
+        """Dims of each head that RoPE rotates: the leading
+        ``int(head_dim * rotary_pct)``; the rest pass through."""
+        return int(self.head_dim * self.rotary_pct)
 
     @property
     def subquadratic(self) -> bool:

@@ -67,16 +67,26 @@ def init_norm(cfg, d, dtype=jnp.bfloat16):
 # RoPE
 
 
-def rope_angles(positions, head_dim, theta):
-    """positions [*(pos)] -> (sin, cos) each [*(pos), head_dim/2], fp32."""
-    half = head_dim // 2
+def rope_angles(positions, rot_dim, theta):
+    """positions [*(pos)] -> (sin, cos) each [*(pos), rot_dim/2], fp32.
+
+    ``rot_dim`` is the rotated width of a head (``cfg.rope_dim``): the whole
+    head, or its leading part under partial rotary."""
+    half = rot_dim // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[..., None] * freqs
     return jnp.sin(ang), jnp.cos(ang)
 
 
 def apply_rope(x, sin, cos):
-    """x [..., H, D]; sin/cos broadcastable to [..., 1, D/2]."""
+    """x [..., H, D]; sin/cos broadcastable to [..., 1, R/2].
+
+    Rotates the first R = 2 * sin.shape[-1] dims of each head as the pairs
+    (i, i + R/2); dims [R, D) pass through unchanged (partial rotary)."""
+    rot = 2 * sin.shape[-1]
+    if rot < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], sin, cos), x[..., rot:]], -1)
     x32 = x.astype(jnp.float32)
     x1, x2 = jnp.split(x32, 2, axis=-1)
     sin = sin[..., None, :]
@@ -325,7 +335,7 @@ def attention_qkv(cfg, x, p, positions):
     k = k.reshape(B, S, Hkv, Dh)
     v = v.reshape(B, S, Hkv, Dh)
     if cfg.use_rope:
-        sin, cos = rope_angles(positions, Dh, cfg.rope_theta)
+        sin, cos = rope_angles(positions, cfg.rope_dim, cfg.rope_theta)
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)
     return q, k, v

@@ -36,8 +36,6 @@ from .layers import (
     init_mlp,
     init_norm,
     mlp_apply,
-    rope_angles,
-    apply_rope,
 )
 
 

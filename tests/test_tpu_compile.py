@@ -17,6 +17,12 @@ reads that off the compiled program: its temporaries stay below one layer's
 K slice, and no ``copy`` or ``dynamic-slice`` produces a buffer of the
 arena's shape or of one layer's slice.
 
+nemotron-4-15b's step is compiled the same way at its benchmark
+configuration's own sizes (``bench/configs/nemotron-4-15b.stage.json``: 8
+layers, six query heads per kv head, half-head rotary, 5120 pages, 16 rows
+of 288 block-table slots, a 4096-token prompt buffer): weights, arena and
+temporaries fit one v5e, and the arena stays whole there too.
+
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, so these compiles stay in this
 one file.
@@ -25,7 +31,9 @@ one file.
 from __future__ import annotations
 
 import dataclasses
+import json
 import os
+import pathlib
 import re
 
 import jax
@@ -140,4 +148,58 @@ def test_fused_step_keeps_arena_whole(one_chip, chunk):
     assert temp < slice_bytes, f"temporaries {temp / 2**20:.1f} MiB"
     big = _big_copies(hlo, [arena, (LAYERS * ARENA_PAGES, PAGE, hkv, hd),
                             layer_slice, (1, *layer_slice)])
+    assert not big, "\n".join(big[:6])
+
+
+#: what one v5e chip may hold: 16 GB, less what the runtime keeps
+CHIP_BYTES = 14.5 * 2**30
+NEMOTRON = (pathlib.Path(__file__).resolve().parents[1] / "bench" / "configs"
+            / "nemotron-4-15b.stage.json")
+#: the batch mix's longest request: prompt cap and output cap
+NEMOTRON_PROMPT, NEMOTRON_NEW = 4096, 512
+
+
+@pytest.mark.parametrize("chunk", [1, 16], ids=["decode", "chunk16"])
+def test_nemotron_step_fits_and_keeps_arena_whole(one_chip, chunk):
+    from repro.configs.base import ArchConfig
+    from repro.core.pagepool import pool_init
+    from repro.models import build_model
+    from repro.serving import required_pages_per_seq
+    from repro.serving.paged_decode import fused_decode_step
+
+    spec = json.loads(NEMOTRON.read_text())
+    cfg = ArchConfig(**spec["model"])
+    eng = spec["engine"]
+    assert cfg.n_heads // cfg.n_kv_heads == 6 and cfg.rope_dim == 64
+    B, P, ps = eng["max_batch"], eng["num_pages"], eng["page_size"]
+    M = required_pages_per_seq(NEMOTRON_PROMPT, NEMOTRON_NEW, ps)
+    L, hkv, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        build_model(cfg).init, jax.random.PRNGKey(0)))
+    arena = (L, P, ps, hkv, hd)
+    kv = sds(arena, jnp.bfloat16)
+    pool = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                        jax.eval_shape(lambda: pool_init(P)))
+    i32 = jnp.int32
+    compiled = fused_decode_step.lower(
+        params, {"k": kv, "v": kv}, pool, sds((B, M), i32),
+        sds((B, M), jnp.uint32), sds((B,), i32), sds((B,), i32),
+        sds((B,), bool), sds((B, NEMOTRON_PROMPT), i32), sds((B,), i32),
+        sds((2,), jnp.uint32), sds((), jnp.float32), sds((), i32), None,
+        None, sds((), bool), cfg=cfg, impl="pallas", greedy=True,
+        chunk_size=chunk).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    m = compiled.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes
+    print(f"nemotron-4-15b C={chunk}: arguments {m.argument_size_in_bytes} "
+          f"temporaries {m.temp_size_in_bytes} bytes")
+    assert total < CHIP_BYTES, f"{total / 2**30:.2f} GiB"
+    layer_slice = (P, ps, hkv, hd)
+    big = _big_copies(hlo, [arena, (L * P, ps, hkv, hd), layer_slice,
+                            (1, *layer_slice)])
     assert not big, "\n".join(big[:6])
