@@ -14,7 +14,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .paged_attention import paged_attention_pallas, paged_attention_sharded
+from .paged_attention import (grid_counter, paged_attention_pallas,
+                              paged_attention_sharded)
 from .ref import paged_attention_chunked_ref, paged_attention_ref
 
 
@@ -40,7 +41,8 @@ def resolve_attn_impl(impl: str | None, platform: str) -> str:
 
 
 def paged_attention(q, kv, block_tables, lengths, *, impl: str = "ref",
-                    pages_per_compute_block: int = 1, chunk_lens=None,
+                    pages_per_compute_block: int | None = None,
+                    chunk_lens=None,
                     mesh=None):
     """Decode or chunked-prefill attention over the paged pool.
 
@@ -54,7 +56,8 @@ def paged_attention(q, kv, block_tables, lengths, *, impl: str = "ref",
 
     ``pages_per_compute_block`` tiles the Pallas grid: each grid step fetches
     that many KV pages and runs one set of MXU dots over the combined
-    (ppcb*page_size, Hkv, D) tile (ignored by the jnp reference).
+    (ppcb*page_size, Hkv, D) tile; None (the default) is the kernel's own
+    choice from the call's shapes (ignored by the jnp reference).
 
     ``mesh`` (tensor-parallel serving): the jnp reference needs nothing —
     GSPMD partitions it from the head-sharded arena layout — but a
@@ -91,6 +94,23 @@ def paged_attention(q, kv, block_tables, lengths, *, impl: str = "ref",
         pages_per_compute_block=pages_per_compute_block,
         interpret=(impl == "interpret"), chunk_lens=chunk_lens,
     )
+
+
+def attention_grid_counter(*, impl: str, mesh, batch: int, max_pages: int,
+                           page_size: int, n_heads: int, n_kv_heads: int,
+                           head_dim: int, itemsize: int, n_layers: int):
+    """The host-side count of the kernel's grid (``grid_counter``) for
+    :func:`paged_attention` calls of an engine's shapes, on the kv heads
+    each launch holds (a shard's where it runs per shard); None where
+    ``impl`` is the jnp reference, which runs no grid."""
+    if impl == "ref":
+        return None
+    tp = mesh.shape.get("model", 1) if mesh is not None else 1
+    local = n_kv_heads // tp if n_kv_heads % tp == 0 else n_kv_heads
+    return grid_counter(batch=batch, max_pages=max_pages,
+                        page_size=page_size, n_kv_heads=local,
+                        group=n_heads // n_kv_heads, head_dim=head_dim,
+                        itemsize=itemsize, n_layers=n_layers)
 
 
 def speculative_accept(target_toks, chunk_toks, draft_lens):

@@ -17,17 +17,23 @@ amortize-the-validation argument applied along the sequence axis (one
 dispatch, one OA validation for C tokens instead of C of each).
 
 TPU mapping:
-- grid = (batch, ceil(max_pages / pages_per_compute_block)); the block table
-  rides in scalar-prefetch memory (SMEM) so the ``index_map`` can translate
-  virtual page slots to physical page ids *before* the DMAs are issued — the
+- grid = (batch, ceil(max_pages / ppcb)); the block table rides in
+  scalar-prefetch memory (SMEM) so the ``index_map`` can translate virtual
+  page slots to physical page ids *before* the DMAs are issued — the
   pagemap lookup of LRMalloc, done by the DMA engine.
+- The compute block is the kernel's own choice (:func:`compute_block_pages`,
+  a rule over the call's shapes): at least one lane width (128 key
+  positions) of KV per grid step — 8 pages at 16-token pages — halved
+  while the block's reckoned VMEM would pass :data:`VMEM_BUDGET`.  A grid
+  step has a fixed cost whatever it holds, and a one-page block puts only
+  16 key positions on the 128-lane axis, so every softmax, mask and
+  accumulator op would run at 1/8 lane occupancy.  An explicit
+  ``pages_per_compute_block`` overrides the rule (the parity tests).
 - Each grid step assembles a (ppcb*page_size, Hkv, D) KV tile from ``ppcb``
   independently-mapped pages (one BlockSpec per page within the block — the
   pages are scattered in the arena, so each needs its own translation), then
   issues ONE batched MXU dot per kv head for all C queries over the whole
-  tile (bf16 operands, f32 accumulation).  Larger
-  ``ppcb`` ⇒ fewer grid steps, fewer accumulator round-trips, larger dots —
-  the same batching-of-validation amortization OA applies to reclamation.
+  tile (bf16 operands, f32 accumulation).
 - **In-chunk causal mask**: ``lengths[b]`` is the row's TOTAL valid KV
   length including this step's appended chunk; ``chunk_lens[b]`` (1..C) is
   how many of the C query slots are live.  Query j sits at global position
@@ -37,10 +43,14 @@ TPU mapping:
   staying finite while their output is discarded.
 - ``pl.when`` skips the COMPUTE (dots, softmax accumulation, scratch
   round-trips) for blocks that are entirely past ``lengths[b]`` or fully
-  unmapped (every table entry < 0).  Note the BlockSpec DMAs are still
-  issued for skipped blocks — index_maps run regardless of kernel-body
-  predicates — so ragged padding saves FLOPs and accumulator traffic, not
-  HBM reads.
+  unmapped (every table entry < 0).  Dead slots cost no DMA either: an
+  unmapped (-1) slot's index map reads page 0, and the pipeline skips a
+  block's copy when its index repeats the previous grid step's, so once a
+  row's slots are unmapped, and through a dead row, nothing is fetched
+  after the first such block.  The
+  grid step itself is still paid, at a cost that grows with the block's
+  BlockSpecs (the index maps run for each of them on every grid step, so
+  they stay plain lookups).
 - Freed pages remain mapped in the persistent arena, so a stale block table
   entry fetches garbage *safely*; the scheduler's version check discards the
   result (OA semantics — reads validated after the fact).
@@ -48,8 +58,8 @@ TPU mapping:
   and hands the kernel each query row's causal limit as a (G*C, 1) column,
   so every in-kernel array keeps (rows, lanes) in its last two dims and the
   mask needs no vector reshape; D should be a multiple of 128.  At olmo-1b
-  widths (Hkv 16, D 128) the chunk form fits v5e's 16 MiB scoped VMEM at
-  every C the engine builds (``tests/test_tpu_compile.py``).
+  and nemotron-4-15b widths the chosen blocks fit v5e's 16 MiB scoped VMEM
+  at every C the engine builds (``tests/test_tpu_compile.py``).
 
 Weak spots the sweep tests cover: GQA grouping, ragged lengths mid-page,
 unmapped (-1) table entries, page_size not dividing length, max_pages not
@@ -66,6 +76,73 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+#: key positions one grid step covers at the least: one lane width, so the
+#: [Hkv, G*C, span] score, mask and accumulator tiles fill the lane axis
+BLOCK_POSITIONS = 128
+#: what a block's reckoned VMEM may take, under v5e's 16 MiB scoped limit
+#: (the rest is left to Mosaic's own temporaries)
+VMEM_BUDGET = 10 << 20
+
+
+def block_vmem_bytes(ppcb: int, page_size: int, n_kv_heads: int, rows: int,
+                     head_dim: int, itemsize: int = 2) -> int:
+    """VMEM one grid step of a ``ppcb``-page block holds, reckoned in VMEM
+    tiles (8 sublanes of 32 bits, 16 of bf16; 128 lanes): the K and V page
+    blocks, double-buffered, and the tiles assembled from them; K
+    head-major for QK, V in f32 in both layouts for P·V; the f32 scores
+    and P (``[Hkv, rows, span]``); the m/l/acc scratch; the double-buffered
+    q and output blocks.  At both benchmark cells' shapes it reads 0.75 to
+    1.0 of what Mosaic allocates for a v5e (found by bisecting the scoped
+    VMEM limit of a compile), hence the budget's room."""
+    def pad(n, m):
+        return -(-n // m) * m
+
+    sub = 32 // itemsize
+    span = ppcb * page_size
+    head_rows = n_kv_heads * pad(rows, 8)
+    page_block = page_size * pad(n_kv_heads, sub) * head_dim * itemsize
+    return (6 * ppcb * page_block
+            + span * n_kv_heads * head_dim * (itemsize + 2 * 4)
+            + 2 * head_rows * pad(span, 128) * 4
+            + head_rows * (head_dim + 2 * 128) * 4
+            + 4 * n_kv_heads * pad(rows, sub) * head_dim * itemsize)
+
+
+def compute_block_pages(page_size: int, max_pages: int, n_kv_heads: int,
+                        rows: int, head_dim: int, itemsize: int = 2) -> int:
+    """Pages per grid step for a call of these shapes (``rows`` = G*C query
+    rows per kv head): :data:`BLOCK_POSITIONS` key positions' worth, capped
+    at ``max_pages``, halved while :func:`block_vmem_bytes` passes
+    :data:`VMEM_BUDGET`."""
+    ppcb = min(max(BLOCK_POSITIONS // page_size, 1), max(max_pages, 1))
+    while ppcb > 1 and block_vmem_bytes(ppcb, page_size, n_kv_heads, rows,
+                                        head_dim, itemsize) > VMEM_BUDGET:
+        ppcb //= 2
+    return ppcb
+
+
+def grid_counter(*, batch: int, max_pages: int, page_size: int,
+                 n_kv_heads: int, group: int, head_dim: int,
+                 itemsize: int = 2, n_layers: int = 1):
+    """Host-side count of the kernel's grid for calls of these fixed shapes
+    (``group`` query heads per kv head), made once per engine.  Returns
+    ``count(lengths, chunk_size) -> (grid steps, live grid steps)`` over
+    ``n_layers`` calls of width ``chunk_size`` whose rows attend
+    ``lengths`` (one host int per live row; the other rows hold none), at
+    the block the kernel chooses for that width."""
+    @functools.cache
+    def block(chunk_size):
+        ppcb = compute_block_pages(page_size, max_pages, n_kv_heads,
+                                   group * chunk_size, head_dim, itemsize)
+        return ppcb * page_size, -(-max_pages // ppcb)
+
+    def count(lengths, chunk_size: int) -> tuple[int, int]:
+        span, nblocks = block(chunk_size)
+        live = sum(min(-(-n // span), nblocks) for n in lengths)
+        return n_layers * batch * nblocks, n_layers * live
+
+    return count
 
 
 def _kernel(
@@ -154,7 +231,7 @@ def _kernel(
 )
 def paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths, *,
                            page_size: int, n_kv_heads: int,
-                           pages_per_compute_block: int = 1,
+                           pages_per_compute_block: int | None = None,
                            interpret: bool, chunk_lens=None):
     """q [B, Hq, D] (decode) or [B, C, Hq, D] (chunk) -> same shape back.
 
@@ -163,7 +240,9 @@ def paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths, *,
     count for the in-chunk causal mask (None = every slot live — for the
     decode form that is the classic single-query mask).  ``interpret`` runs
     the kernel body in the Pallas interpreter (CPU tests) instead of
-    compiling it with Mosaic.  See the module docstring for layout rules.
+    compiling it with Mosaic.  ``pages_per_compute_block`` None is the
+    kernel's own block (:func:`compute_block_pages`).  See the module
+    docstring for layout rules.
     """
     squeeze = q.ndim == 3
     if squeeze:
@@ -174,8 +253,11 @@ def paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths, *,
     R = G * C
     if chunk_lens is None:
         chunk_lens = jnp.full((B,), C, jnp.int32)
-    ppcb = max(int(pages_per_compute_block), 1)
     max_pages = block_tables.shape[1]
+    ppcb = (compute_block_pages(page_size, max_pages, Hkv, R, D,
+                                k_pages.dtype.itemsize)
+            if pages_per_compute_block is None
+            else max(int(pages_per_compute_block), 1))
     nblocks = -(-max_pages // ppcb)
     if nblocks * ppcb != max_pages:
         block_tables = jnp.pad(
@@ -234,7 +316,7 @@ def paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths, *,
 
 def paged_attention_sharded(q, k_pages, v_pages, block_tables, lengths, *,
                             mesh, page_size: int, n_kv_heads: int,
-                            pages_per_compute_block: int = 1,
+                            pages_per_compute_block: int | None = None,
                             interpret: bool, chunk_lens=None):
     """Tensor-parallel Pallas dispatch: ``shard_map`` over the mesh's 'model'
     axis, one kernel launch per shard on its LOCAL head slab.

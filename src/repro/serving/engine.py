@@ -36,6 +36,7 @@ from repro.core.chaos import ChaosAllocator, ChaosConfig
 from repro.core.pagepool import DEFAULT_PAGES_PER_SUPERBLOCK, DevicePagePool
 from repro.core.reclaim_policy import ReclamationPolicy, make_policy
 from repro.core.vm import ReleaseStrategy
+from repro.kernels.ops import attention_grid_counter
 from repro.launch.mesh import make_serving_mesh
 from .kv_manager import KVCacheManager
 from .paged_decode import kv_storage_init
@@ -53,7 +54,6 @@ class PagedServingEngine:
                  max_batch: int = 8, max_pages_per_seq: int | None = None,
                  attn_impl: str | None = None, greedy: bool = True,
                  temperature: float = 1.0, seed: int = 0,
-                 pages_per_compute_block: int = 1,
                  pages_per_superblock: int = DEFAULT_PAGES_PER_SUPERBLOCK,
                  release_strategy: ReleaseStrategy = ReleaseStrategy.MADVISE,
                  release_quiescence: int | str | None = None,
@@ -142,8 +142,16 @@ class PagedServingEngine:
             self.runner = ModelRunner(
                 cfg, self.params, attn_impl=attn_impl, greedy=greedy,
                 temperature=temperature, seed=seed,
-                pages_per_compute_block=pages_per_compute_block,
                 mesh=self.mesh, device=device)
+            # the attention kernel's grid, counted on the host each step
+            self._attn_grid = attention_grid_counter(
+                impl=self.runner.attn_impl, mesh=self.mesh,
+                batch=max_batch,
+                max_pages=self.kv_manager.max_pages_per_seq,
+                page_size=page_size, n_heads=cfg.n_heads,
+                n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
+                itemsize=self.kv_manager.kv["k"].dtype.itemsize,
+                n_layers=cfg.n_layers)
             self.scheduler = Scheduler(
                 self.kv_manager, self.stats, num_pages=num_pages,
                 page_size=page_size, max_batch=max_batch,
@@ -197,13 +205,22 @@ class PagedServingEngine:
         scheduler-overlap race; tests)."""
         if not self.scheduler.running:
             return
-        C, budget, drafts = self.scheduler.plan_chunk()
+        C, budget, drafts = self._plan()
         do_validate = self.scheduler.plan_validate()
         res = self.runner.execute(self.kv_manager, chunk_size=C,
                                   budget=budget, drafts=drafts,
                                   do_validate=do_validate)
         self.scheduler.absorb(res, C, budget, inject_preemption_of,
                               drafts=drafts)
+
+    def _plan(self):
+        """The scheduler's plan for the next step, with the attention
+        kernel's grid it implies counted into the stats."""
+        C, budget, drafts = self.scheduler.plan_chunk()
+        if self._attn_grid is not None:
+            self.stats.record_attn_grid(*self._attn_grid(
+                self.scheduler.attended_lengths(budget, drafts), C))
+        return C, budget, drafts
 
     def launch_step(self):
         """Dispatch one step WITHOUT collecting its host transfer; returns a
@@ -212,7 +229,7 @@ class PagedServingEngine:
         any — jax dispatch is async, so the fused steps overlap."""
         if not self.scheduler.running:
             return None
-        C, budget, drafts = self.scheduler.plan_chunk()
+        C, budget, drafts = self._plan()
         do_validate = self.scheduler.plan_validate()
         return (self.runner.launch(self.kv_manager, chunk_size=C,
                                    budget=budget, drafts=drafts,

@@ -119,8 +119,7 @@ def max_chunk_pages(chunk_size: int, page_size: int) -> int:
 
 
 def _chunk_core(params, kv, block_tables, lengths, tokens, n_new, *, cfg,
-                impl: str = "ref", pages_per_compute_block: int = 1,
-                write_ok=None, mesh=None):
+                impl: str = "ref", write_ok=None, mesh=None):
     """Model math for a C-token chunk per row (C = 1 is plain decode).
 
     tokens [B, C] int32 — chunk inputs; position of tokens[b, j] is
@@ -180,7 +179,6 @@ def _chunk_core(params, kv, block_tables, lengths, tokens, n_new, *, cfg,
             bt = jnp.where(block_tables >= 0, block_tables + off, -1)
             att = paged_attention(
                 q, {"k": k_all, "v": v_all}, bt, total_len, impl=impl,
-                pages_per_compute_block=pages_per_compute_block,
                 chunk_lens=n_new, mesh=mesh)
             x = x + att.reshape(B, C, -1) @ blk["attn"]["wo"]
         with jax.named_scope("layer.mlp"):
@@ -222,8 +220,8 @@ def paged_decode_step(params, kv, block_tables, lengths, tokens, *, cfg,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("cfg", "impl", "greedy", "pages_per_compute_block",
-                     "chunk_size", "speculative", "mesh"),
+    static_argnames=("cfg", "impl", "greedy", "chunk_size", "speculative",
+                     "mesh"),
     donate_argnums=(1, 2, 3, 4, 5, 6),
 )
 def fused_decode_step(params, kv, pool, block_tables, snapshot, lengths,
@@ -231,9 +229,8 @@ def fused_decode_step(params, kv, pool, block_tables, snapshot, lengths,
                       temperature, chunk_budget=1, draft_toks=None,
                       draft_lens=None, do_validate=None, *, cfg,
                       impl: str = "ref",
-                      greedy: bool = True, pages_per_compute_block: int = 1,
-                      chunk_size: int = 1, speculative: bool = False,
-                      mesh=None):
+                      greedy: bool = True, chunk_size: int = 1,
+                      speculative: bool = False, mesh=None):
     """The sync-free batched step: one dispatch, one host transfer — now
     covering up to ``chunk_size`` prompt tokens per prefilling row.
 
@@ -416,8 +413,7 @@ def fused_decode_step(params, kv, pool, block_tables, snapshot, lengths,
     with jax.named_scope("step.layers"):
         x, kv = _chunk_core(
             params, kv, block_tables, lengths, tok_in, n_new, cfg=cfg,
-            impl=impl, pages_per_compute_block=pages_per_compute_block,
-            write_ok=grant_ok, mesh=mesh)
+            impl=impl, write_ok=grant_ok, mesh=mesh)
 
     # (5) on-device token selection.  Plain path: only the chunk's last
     # live position is unembedded — logits never leave the device.

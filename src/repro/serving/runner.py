@@ -53,8 +53,7 @@ class ModelRunner:
 
     def __init__(self, cfg, params, *, attn_impl: str | None = None,
                  greedy: bool = True, temperature: float = 1.0,
-                 seed: int = 0, pages_per_compute_block: int = 1,
-                 mesh=None, device=None):
+                 seed: int = 0, mesh=None, device=None):
         self.cfg = cfg
         self.params = params
         # attention follows the platform of the devices this runner drives
@@ -63,7 +62,6 @@ class ModelRunner:
                else device if device is not None else jax.devices()[0])
         self.attn_impl = resolve_attn_impl(attn_impl, dev.platform)
         self.greedy = greedy
-        self.pages_per_compute_block = pages_per_compute_block
         # tensor-parallel serving: a ('data','model') mesh threads through
         # to the fused step as a STATIC arg (sharding constraints + the
         # shard_map'ed pallas dispatch); None = the classic 1-device path
@@ -140,7 +138,6 @@ class ModelRunner:
                 draft_args[0], draft_args[1],
                 self._val_true if do_validate else self._val_false,
                 cfg=self.cfg, impl=self.attn_impl, greedy=self.greedy,
-                pages_per_compute_block=self.pages_per_compute_block,
                 chunk_size=chunk_size, speculative=speculative, mesh=self.mesh)
             kvm.install_state(DeviceStepState(
                 kv, pool, bt, snap, lengths, last,

@@ -934,6 +934,21 @@ class Scheduler:
             return C, budget, None
         return 1, 1, None
 
+    def attended_lengths(self, budget: int,
+                         drafts: dict | None = None) -> list[int]:
+        """The KV length each running row's attention spans in the step
+        just planned, from the host mirrors: its committed tokens plus what
+        the fused step appends (a prefilling row's share of ``budget``, a
+        decoding row's last token and its drafts)."""
+        drafts = drafts or {}
+        out = []
+        for r in self.running:
+            left = len(r.prompt) - r.committed
+            n = (min(budget, left) if left > 0
+                 else 1 + len(drafts.get(r.slot, ())))
+            out.append(r.committed + n)
+        return out
+
     def _prefill_budget(self, C: int, n_prefill: int) -> int:
         """Sarathi budget for the prefilling rows of a C-wide step: one
         token reserved per decoding row, the rest split across prefills,

@@ -136,6 +136,12 @@ class EngineStats:
     mean_ttft_seconds: float = 0.0
     chunked_steps: int = 0
     prefill_tokens_chunked: int = 0
+    # paged-attention kernel grid, reckoned on the host from the rows'
+    # lengths: grid steps the step's kernel calls span (rows x blocks x
+    # layers) and those that hold live KV; 0 where attention runs the jnp
+    # reference
+    attn_grid_blocks: int = 0
+    attn_live_blocks: int = 0
     # speculative decoding accounting (draft-and-verify, the sequence-axis
     # OA validate/commit); accept_rate is the running tokens_accepted /
     # tokens_drafted, draft_k the live AIMD cap (a gauge, not a counter)
@@ -181,6 +187,12 @@ class EngineStats:
         self.steps += 1
         if chunked:
             self.chunked_steps += 1
+
+    def record_attn_grid(self, grid: int, live: int) -> None:
+        """One step's kernel calls spanned ``grid`` grid steps, ``live`` of
+        them over live KV."""
+        self.attn_grid_blocks += grid
+        self.attn_live_blocks += live
 
     def record_commit(self, n: int, chunked_prefill: bool = False) -> None:
         """``n`` tokens committed by one row (``chunked_prefill``: they were
@@ -391,6 +403,8 @@ def aggregate_stats(parts: list[EngineStats],
         total.prefix_evictions += s.prefix_evictions
         total.chunked_steps += s.chunked_steps
         total.prefill_tokens_chunked += s.prefill_tokens_chunked
+        total.attn_grid_blocks += s.attn_grid_blocks
+        total.attn_live_blocks += s.attn_live_blocks
         total.tokens_drafted += s.tokens_drafted
         total.tokens_accepted += s.tokens_accepted
         total.spec_steps += s.spec_steps

@@ -48,12 +48,13 @@ def test_paged_attention_matches_ref(case, dtype):
         atol=tol, rtol=tol)
 
 
-@pytest.mark.parametrize("ppcb", [1, 2, 4])
+@pytest.mark.parametrize("ppcb", [1, 2, 4, None])
 @pytest.mark.parametrize("case", CASES, ids=[str(c) for c in CASES])
 def test_multi_page_blocks_match_ref(case, ppcb):
     """pages_per_compute_block tiling must be bit-identical (fp32 accum) to
     the single-page walk across the GQA/ragged/unmapped sweep — including
-    max_pages not divisible by ppcb (padded with -1 slots)."""
+    max_pages not divisible by ppcb (padded with -1 slots).  None is the
+    kernel's own block."""
     P, page, Hkv, D, Hq, B, maxp = case
     rng = jax.random.PRNGKey(hash(case) % 2**31)
     ks = jax.random.split(rng, 3)
@@ -161,16 +162,46 @@ def _chunked_case(case, C, seed=7):
 
 
 @pytest.mark.parametrize("C", [1, 8, 16])
-@pytest.mark.parametrize("ppcb", [1, 2, 4])
+@pytest.mark.parametrize("ppcb", [1, 2, 4, None])
 def test_chunked_matches_ref_sweep(C, ppcb):
     """Chunked Pallas kernel vs the chunked jnp oracle across C ∈ {1,8,16} ×
-    ppcb ∈ {1,2,4}: GQA, ragged lengths, unmapped slots, page-boundary
-    straddles, rows finishing mid-chunk (the ISSUE acceptance sweep)."""
+    ppcb ∈ {1,2,4, the kernel's own}: GQA, ragged lengths, unmapped slots,
+    page-boundary straddles, rows finishing mid-chunk."""
     case = (16, 4, 2, 16, 4, 3, 6)  # page_size 4 < C: chunks straddle pages
     q, kv, bt, lens, cls = _chunked_case(case, C)
     ref = paged_attention_chunked_ref(q, kv["k"], kv["v"], bt, lens, cls)
     out = paged_attention(q, kv, bt, lens, impl="interpret",
                           pages_per_compute_block=ppcb, chunk_lens=cls)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_chosen_block_pads_a_ragged_table():
+    """The kernel's own block at 16-token pages is 8 pages; a 20-slot table
+    is then 3 blocks, the last padded with -1 slots.  At G=6, C=16 with
+    rows finishing mid-chunk and rows whose live pages end inside the
+    padded block, it still matches the oracle."""
+    from repro.kernels.paged_attention import compute_block_pages
+
+    P, page, Hkv, D, G, C, slots = 64, 16, 2, 128, 6, 16, 20
+    assert compute_block_pages(page, slots, Hkv, G * C, D, 4) == 8
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    kv = {"k": jax.random.normal(ks[0], (P, page, Hkv, D), jnp.float32),
+          "v": jax.random.normal(ks[1], (P, page, Hkv, D), jnp.float32)}
+    q = jax.random.normal(ks[2], (3, C, Hkv * G, D), jnp.float32)
+    # a row 19 pages long (into the padded third block), one ending in the
+    # second block, one in the first; chunks of 16, 5 and 1 live queries
+    lens = jnp.array([300, 130, 17], jnp.int32)
+    cls = jnp.array([16, 5, 1], jnp.int32)
+    perm = np.random.default_rng(5).permutation(P)
+    bt = np.full((3, slots), -1, np.int32)
+    used = 0
+    for b, n in enumerate(-(-np.asarray(lens) // page)):
+        bt[b, :n] = perm[used:used + n]
+        used += n
+    bt = jnp.asarray(bt)
+    ref = paged_attention_chunked_ref(q, kv["k"], kv["v"], bt, lens, cls)
+    out = paged_attention(q, kv, bt, lens, impl="interpret", chunk_lens=cls)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -287,3 +318,16 @@ def test_speculative_accept_edge_cases():
     assert int(speculative_accept(jnp.asarray(tgt), jnp.asarray(chunk),
                                   jnp.asarray(d))[0]) == 1
     assert speculative_accept_ref(tgt, chunk, d)[0] == 1
+
+
+def test_grid_counter_counts_blocks_by_hand():
+    """The host count of the kernel's grid: rows x blocks x layers, and
+    for each live row the blocks its length reaches.  At 16-token pages
+    the kernel's block is 8 pages (128 positions), so a 20-slot table is 3
+    blocks; rows of 1, 128, 129 and 320 positions reach 1, 1, 2 and 3."""
+    from repro.kernels.paged_attention import grid_counter
+
+    count = grid_counter(batch=6, max_pages=20, page_size=16, n_kv_heads=2,
+                         group=6, head_dim=128, n_layers=4)
+    assert count([1, 128, 129, 320], 16) == (4 * 6 * 3, 4 * (1 + 1 + 2 + 3))
+    assert count([], 1) == (4 * 6 * 3, 0)

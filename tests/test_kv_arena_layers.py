@@ -20,6 +20,7 @@ out, written and attended over, and the layers stacked back.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +29,6 @@ import pytest
 
 from repro.configs import get_config, reduced
 from repro.core import pagepool as pp
-from repro.kernels.ops import paged_attention
 from repro.models import build_model
 from repro.models.layers import apply_norm, attention_qkv, mlp_apply
 from repro.models.transformer import embed_tokens
@@ -40,8 +40,7 @@ L, P, PS, B, M, C, CAP = 3, 8, 4, 4, 6, 8, 16
 
 
 def _sliced_core(params, kv, block_tables, lengths, tokens, n_new, *, cfg,
-                 impl="ref", pages_per_compute_block=1, write_ok=None,
-                 mesh=None):
+                 impl="ref", write_ok=None, mesh=None):
     """The layer loop with each layer's KV sliced out of [L, P, ...]."""
     Bq, Cq = tokens.shape
     np_, page_size = kv["k"].shape[1:3]
@@ -62,9 +61,8 @@ def _sliced_core(params, kv, block_tables, lengths, tokens, n_new, *, cfg,
         q, k, v = attention_qkv(cfg, h, blk["attn"], positions)
         kl = kl.at[pidx, slot].set(k, mode="drop")
         vl = vl.at[pidx, slot].set(v, mode="drop")
-        att = paged_attention(
+        att = paged_decode.paged_attention(
             q, {"k": kl, "v": vl}, block_tables, total_len, impl=impl,
-            pages_per_compute_block=pages_per_compute_block,
             chunk_lens=n_new, mesh=mesh)
         x = x + att.reshape(Bq, Cq, -1) @ blk["attn"]["wo"]
         x = x + mlp_apply(cfg, apply_norm(cfg, x, blk["ln2"]), blk["mlp"])
@@ -105,26 +103,28 @@ def _inputs():
             jax.random.PRNGKey(0), jnp.float32(1.0), jnp.int32(C))
 
 
-def _sliced_step():
-    """``fused_decode_step`` traced over ``_sliced_core``."""
+def _step(core, ppcb):
+    """``fused_decode_step`` traced over ``core``, its kernel calls at a
+    compute block of ``ppcb`` pages (the kernel's own override)."""
     def step(*args, **kw):
-        real = paged_decode._chunk_core
-        paged_decode._chunk_core = _sliced_core
+        real = paged_decode._chunk_core, paged_decode.paged_attention
+        paged_decode._chunk_core = core
+        paged_decode.paged_attention = functools.partial(
+            real[1], pages_per_compute_block=ppcb)
         try:
             return fused_decode_step.__wrapped__(*args, **kw)
         finally:
-            paged_decode._chunk_core = real
-    return jax.jit(step, static_argnames=("cfg", "impl", "chunk_size",
-                                          "pages_per_compute_block"))
+            paged_decode._chunk_core, paged_decode.paged_attention = real
+    return jax.jit(step, static_argnames=("cfg", "impl", "chunk_size"))
 
 
 @pytest.mark.parametrize("impl,ppcb", [("ref", 1), ("interpret", 1),
                                        ("interpret", 2)])
 def test_flat_arena_writes_only_its_layers_pages(params, impl, ppcb):
-    kw = dict(cfg=CFG, impl=impl, chunk_size=C, pages_per_compute_block=ppcb)
+    kw = dict(cfg=CFG, impl=impl, chunk_size=C)
     kv0 = {n: np.asarray(a, np.float32) for n, a in _inputs()[0].items()}
-    out = fused_decode_step(params, *_inputs(), **kw)
-    ref = _sliced_step()(params, *_inputs(), **kw)
+    out = _step(paged_decode._chunk_core, ppcb)(params, *_inputs(), **kw)
+    ref = _step(_sliced_core, ppcb)(params, *_inputs(), **kw)
 
     kv, bt = out[0], np.asarray(out[2])
     valid, grant, adv = (np.asarray(out[i]) for i in (7, 8, 10))

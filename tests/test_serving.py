@@ -178,3 +178,45 @@ def test_randomized_workloads_always_finish_correctly():
         for r, a in zip(rs, ra):
             assert r.state == "finished", (trial, r.rid)
             assert r.generated == a.generated, (trial, r.rid)
+
+
+@pytest.mark.parametrize("impl,page,max_pages,expect_ppcb", [
+    ("interpret", 64, 3, 2), ("interpret", 16, 10, 8), ("ref", 16, 10, 0)],
+    ids=["interpret-ppcb2", "interpret-kernel-choice", "ref"])
+def test_attn_grid_counters_follow_row_lengths(impl, page, max_pages,
+                                               expect_ppcb):
+    """``attn_grid_blocks``/``attn_live_blocks`` count the kernel's grid
+    over every layer: max_batch rows x ceil(max_pages / ppcb) blocks, and
+    for each running row the blocks its KV length (committed + this step's
+    chunk) reaches.  The kernel's own block is a lane width of keys, 128
+    positions: 2 pages of 64 (a 3-slot table is 2 blocks, the second
+    padded), 8 pages of 16 (10 slots, 2 blocks); the 150-token prompt
+    reaches the second block on the fifth step.  The jnp reference runs no
+    grid."""
+    B, C = 3, 32
+    eng = PagedServingEngine(CFG, PARAMS, num_pages=64, page_size=page,
+                             max_batch=B, max_pages_per_seq=max_pages,
+                             attn_impl=impl, prefill_chunk=C)
+    for p in ([5, 9, 13, 2, 7], [7, 11], [3 + i % 97 for i in range(150)]):
+        eng.submit(p, 5)
+    crossed = False
+    for _ in range(6):
+        eng.scheduler.admit()
+        before = [(len(r.prompt), r.committed) for r in eng.running]
+        grid0, live0 = eng.stats.attn_grid_blocks, eng.stats.attn_live_blocks
+        eng.step()
+        eng.scheduler.maintain()
+        if not expect_ppcb:
+            assert (eng.stats.attn_grid_blocks, eng.stats.attn_live_blocks) \
+                == (0, 0)
+            continue
+        span = expect_ppcb * page
+        # a prefilling row appends min(C, what is left of its prompt) in a
+        # C-wide step; a decoding row appends its one token
+        lens = [c + (min(C, n - c) if c < n else 1) for n, c in before]
+        crossed |= max(lens) > span
+        assert eng.stats.attn_grid_blocks - grid0 == (
+            CFG.n_layers * B * -(-max_pages // expect_ppcb))
+        assert eng.stats.attn_live_blocks - live0 == (
+            CFG.n_layers * sum(-(-n // span) for n in lens))
+    assert crossed or not expect_ppcb

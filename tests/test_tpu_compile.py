@@ -6,7 +6,9 @@ is described, not attached, and refuses what the chip would refuse — Mosaic
 layouts it cannot lower, more scoped VMEM than the kernel may use.  Each
 case is an executable shape the serving engine builds: decode (C=1), chunked
 prefill (C=16), a speculative chunk (K=8 drafts, C=9) and a compute block of
-two pages.
+two pages; and, at each benchmark cell's shapes (olmo-1b; nemotron-4-15b's
+eight kv heads of six query heads each), decode and C=16 at the block the
+kernel chooses itself, which must fit the scoped VMEM.
 
 The fused serving step, compiled the same way with a small depth and arena
 (4 layers, 512 pages), must keep the KV arena whole through the layer loop.
@@ -41,7 +43,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.paged_attention import paged_attention_pallas
+from repro.kernels.paged_attention import (
+    BLOCK_POSITIONS, VMEM_BUDGET, block_vmem_bytes, compute_block_pages,
+    paged_attention_pallas)
 
 HEADS, HEAD_DIM, PAGE, PAGES, BATCH, MAX_PAGES = 16, 128, 16, 2048, 8, 40
 
@@ -97,6 +101,61 @@ def test_paged_attention_compiles_for_v5e(one_chip, chunk, ppcb):
         sds((BATCH, MAX_PAGES), jnp.int32), sds((BATCH,), jnp.int32),
         chunk_lens).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+#: (kv heads, query heads per kv head, block-table slots, arena pages) of
+#: each benchmark cell's kernel call: olmo-1b's 32 rows of 160 slots over a
+#: 2560-page arena, nemotron-4-15b's 16 rows of 288 over 5120; each
+#: arena is flattened over the layers, as the fused step hands it over
+CELL_SHAPES = {"olmo-1b": (16, 1, 160, 16 * 2560, 32),
+               "nemotron-4-15b": (8, 6, 288, 8 * 5120, 16)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+@pytest.mark.parametrize("chunk", [1, 16], ids=["decode", "chunk16"])
+def test_chosen_block_compiles_at_cell_shapes(one_chip, cell, chunk):
+    """The kernel at its own block (``pages_per_compute_block=None``) for
+    each cell's shapes: one lane width of keys a grid step, and it fits the
+    scoped VMEM (the compile refuses a kernel that does not)."""
+    hkv, g, slots, arena, batch = CELL_SHAPES[cell]
+    assert compute_block_pages(PAGE, slots, hkv, g * chunk,
+                               HEAD_DIM) * PAGE >= BLOCK_POSITIONS
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    hq = hkv * g
+    q_shape = ((batch, hq, HEAD_DIM) if chunk == 1
+               else (batch, chunk, hq, HEAD_DIM))
+    pages = sds((arena, PAGE, hkv, HEAD_DIM), jnp.bfloat16)
+    chunk_lens = None if chunk == 1 else sds((batch,), jnp.int32)
+
+    def attend(q, k, v, bt, lengths, cl):
+        return paged_attention_pallas(
+            q, k, v, bt, lengths, page_size=PAGE, n_kv_heads=hkv,
+            interpret=False, chunk_lens=cl)
+
+    compiled = jax.jit(attend).lower(
+        sds(q_shape, jnp.bfloat16), pages, pages,
+        sds((batch, slots), jnp.int32), sds((batch,), jnp.int32),
+        chunk_lens).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_compute_block_pages_rule():
+    """A lane width of keys at 16-token pages (8 pages), capped at the
+    table's slots, one page where pages are a lane wide already, and halved
+    under the VMEM budget for many query rows per kv head."""
+    assert compute_block_pages(16, 288, 8, 96, 128) == 8
+    assert compute_block_pages(16, 160, 16, 1, 128) == 8
+    assert compute_block_pages(16, 5, 16, 16, 128) == 5
+    assert compute_block_pages(128, 64, 8, 6, 128) == 1
+    # 16 kv heads of 8 x 16 query rows: 8 pages pass the budget, 4 do not
+    assert block_vmem_bytes(8, 16, 16, 128, 128) > VMEM_BUDGET
+    assert block_vmem_bytes(4, 16, 16, 128, 128) <= VMEM_BUDGET
+    assert compute_block_pages(16, 288, 16, 128, 128) == 4
+    # no block fits 8 x 64 rows: one page, the least there is
+    assert compute_block_pages(16, 288, 8, 8 * 64, 128) == 1
 
 
 LAYERS, ARENA_PAGES, PROMPT_CAP = 4, 512, 64
