@@ -199,45 +199,6 @@ def _checks(all_rows, crashed=()) -> bool:
               f">= {r['gate_threshold']}",
               r["goodput_ratio"] >= r["gate_threshold"])
 
-    # reclamation-matrix gates (BENCH_reclaim.json): the policies' defining
-    # behaviours measured on one stack — epoch-grace must actually earn its
-    # keep (>=90% of steady-state validation passes skipped), interval must
-    # run zero passes, OA must validate every step, and NO policy may hold
-    # the mapped watermark above 25% of peak after a drain under madvise
-    # (deferred frees delay the release, they must not lose it)
-    rm = {r["method"]: r for r in all_rows if r["bench"] == "reclaim_matrix"}
-    if "epoch-grace/steady" in rm:
-        r = rm["epoch-grace/steady"]
-        _gate(gates, f"reclaim_matrix: epoch-grace skips >=90% of "
-              f"steady-state validations (got {r['skip_ratio']})",
-              r["skip_ratio"], ">= 0.9", r["skip_ratio"] >= 0.9)
-    if "oa-validate/steady" in rm:
-        r = rm["oa-validate/steady"]
-        _gate(gates, "reclaim_matrix: oa-validate validates every step",
-              f"passes={r['validation_passes']},steps={r['steps']}",
-              "passes == steps and skipped == 0",
-              r["validation_passes"] == r["steps"]
-              and r["validation_skipped"] == 0)
-    if "interval/steady" in rm:
-        r = rm["interval/steady"]
-        _gate(gates, "reclaim_matrix: interval runs zero validation passes",
-              r["validation_passes"], "== 0", r["validation_passes"] == 0)
-    for pol in ("oa-validate", "epoch-grace", "interval"):
-        key = f"{pol}/madvise"
-        if key in rm:
-            r = rm[key]
-            _gate(gates, f"reclaim_matrix/{key}: mapped watermark follows "
-                  f"load (ratio {r['watermark_ratio']})",
-                  r["watermark_ratio"], "<= 0.25",
-                  r["watermark_ratio"] <= 0.25
-                  and r["superblocks_released"] > 0)
-        key = f"{pol}/keep"
-        if key in rm:
-            _gate(gates, f"reclaim_matrix/{key}: closed pool stays mapped "
-                  f"(ratio {rm[key]['watermark_ratio']})",
-                  rm[key]["watermark_ratio"], ">= 0.99",
-                  rm[key]["watermark_ratio"] >= 0.99)
-
     mr = [r for r in all_rows if r["bench"] == "memory_release"]
     for r in mr:
         # every released persistent superblock (64 KiB) must actually leave
@@ -295,7 +256,6 @@ SUITE = [
     ("hash_table", "fig5_fig6_hash_table"),
     ("memory_release", "fig3_memory_release"),
     ("memory_release_device", "fig3_device_memory_release"),
-    ("reclaim_matrix", "reclaim_policy_matrix"),
     ("paged_attention_bench", "device_paged_attention"),
     ("decode_throughput", "decode_throughput"),
     ("prefix_cache", "prefix_cache_sharing"),

@@ -127,10 +127,8 @@ def _drive_open_loop(eng, events, vocab: int, max_wall_s: float):
             eng.step()
             eng.scheduler.maintain()
         elif eng.scheduler.queue:
-            # blocked on memory with nothing running: apply deferred frees
-            if not eng._reclaim_policy.drain_pending():
-                raise MemoryError("open-loop drive wedged: queue non-empty, "
-                                  "nothing running, nothing to drain")
+            raise MemoryError("open-loop drive wedged: queue non-empty, "
+                              "nothing running")
         elif cursor < len(events):
             # idle between arrivals: sleep toward the next event
             time.sleep(min(0.005, max(0.0, events[cursor].t - now)))

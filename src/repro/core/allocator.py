@@ -16,13 +16,11 @@ stack's KV manager (``repro.serving.kv_manager``) talks *only* to this
 surface — the cross-layer contract tests in ``tests/test_layering.py``
 drive the manager with a pure-host fake to prove nothing reaches around it.
 
-Because the protocol is the ONLY seam the stack sees, reclamation schemes
-can be swapped behind it: ``core/reclaim_policy.py`` puts a
-:class:`~repro.core.reclaim_policy.ReclamationPolicy` in front of any
-implementation (the interval policy wraps ``free``/``unshare`` in a limbo
-list; the chaos layer ``core/chaos.py`` wraps the same surface for fault
-injection) and the differential tests in ``tests/test_reclaim_diff.py``
-prove the serving stack is token-exact under every backend.
+Because the protocol is the ONLY seam the stack sees, a wrapper can stand
+in front of any implementation: the chaos layer ``core/chaos.py`` wraps
+``alloc``/``free``/``release`` for fault injection, and
+``tests/test_oa_reclaim.py`` proves the serving stack token-exact under
+its faults.
 
 The protocol's vocabulary is the paper's:
 

@@ -36,12 +36,6 @@ grants, only the host-protocol surface), and forwards every attribute it
 does not own, so the engine's introspection surface keeps working when a
 pool is wrapped.  All randomness comes from one ``numpy`` Generator seeded
 by :class:`ChaosConfig` — a chaos run is exactly reproducible.
-
-The reclamation policies (``core/reclaim_policy.py``) compose with this
-layer: the engine wraps ``policy.wrap(ChaosAllocator(pool))``, so the
-interval policy's limbo defers the very frees the fault schedule perturbs,
-and both wrappers follow the same forwarding discipline (``state``
-pass-through, ``__getattr__`` delegation, a chainable ``flush``).
 """
 
 from __future__ import annotations

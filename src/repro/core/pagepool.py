@@ -599,13 +599,10 @@ class DevicePagePool:
     (``serving.paged_decode.fused_decode_step``) keeps threading the raw
     pytree through its fused dispatch and hands it back via ``state``.
 
-    The pool itself is scheme-agnostic: versions bump on every
-    zero-transition and release regardless of whether a reader ever checks
-    them, so the reclamation policies in ``core/reclaim_policy.py`` can
-    elide the per-step validation pass (epoch-grace, interval) or defer the
-    frees (interval limbo) purely ABOVE this surface — no pool change, no
-    second code path, and ``oa-validate`` remains exactly this class used
-    as the paper describes.
+    Versions bump on every zero-transition and release, and the serving
+    stack's fused step validates every row's snapshot against them on
+    every step — OA, exactly as the paper describes, with no free
+    deferred.
     """
 
     def __init__(self, num_pages: int,

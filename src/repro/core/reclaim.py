@@ -23,12 +23,9 @@ Reader protocol (identical for all; NR's checks always pass):
     ... before any CAS: rec.protect(ctx, slot, off) for each involved node,
         then rec.validate(ctx) — one barrier for the whole set (§2.4) ...
 
-The DEVICE-side analogue of this choice-of-scheme lives in
-``core/reclaim_policy.py``: the serving stack's fused step swaps its
-per-step OA validation for epoch-grace skipping or IBR-style interval
-deferral behind one ``ReclamationPolicy`` seam — the same
-precision-vs-throughput spectrum these host schemes span, finally
-benchmarked head-to-head in ``benchmarks/reclaim_matrix.py``.
+The DEVICE-side analogue is OA alone: the serving stack's fused step
+(``serving/paged_decode.py``) validates every row's version snapshot
+against the page pool on every step.
 """
 
 from __future__ import annotations

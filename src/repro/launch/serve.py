@@ -100,9 +100,8 @@ def _replay_trace(eng, events, vocab: int):
             eng.step()
             eng.scheduler.maintain()
         elif eng.scheduler.queue:
-            if not eng._reclaim_policy.drain_pending():
-                raise MemoryError("trace replay wedged: queued work cannot "
-                                  "be admitted and nothing is running")
+            raise MemoryError("trace replay wedged: queued work cannot "
+                              "be admitted and nothing is running")
         elif cursor < len(events):
             time.sleep(min(0.005, max(0.0, events[cursor].t - now)))
         else:

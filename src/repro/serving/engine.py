@@ -18,23 +18,25 @@ the diagram, ``tests/test_layering.py`` pins every arrow):
 The OA story those layers implement end-to-end is unchanged — optimistic
 free with version validation, hazard-pointer-style write pinning, physical
 superblock release, refcounted prefix sharing with fused COW, chunked
-prefill — see each module's docstring and PERF.md.  Data-parallel
-multi-pool serving stacks N of these engines behind one router
-(``serving/parallel.py``); each replica is exactly this facade.  The
-historical surface (``submit/step/run/shrink``, ``pool``, ``kv``,
-``queue``, ``_admit`` …) delegates to the layer that now owns it.
+prefill — see each module's docstring and PERF.md.  OA is the one
+reclamation path: every fused step validates every row's snapshot, and no
+free is deferred.  Data-parallel multi-pool serving stacks N of these
+engines behind one router (``serving/parallel.py``); each replica is
+exactly this facade.  The historical surface (``submit/step/run/shrink``,
+``pool``, ``kv``, ``queue``, ``_admit`` …) delegates to the layer that
+now owns it.
 """
 
 from __future__ import annotations
 
 import contextlib
 import time
+import types
 
 import jax
 
 from repro.core.chaos import ChaosAllocator, ChaosConfig
 from repro.core.pagepool import DEFAULT_PAGES_PER_SUPERBLOCK, DevicePagePool
-from repro.core.reclaim_policy import ReclamationPolicy, make_policy
 from repro.core.vm import ReleaseStrategy
 from repro.kernels.ops import attention_grid_counter
 from repro.launch.mesh import make_serving_mesh
@@ -44,6 +46,10 @@ from .runner import ModelRunner
 from .scheduler import Request, Scheduler  # noqa: F401  (re-export)
 from .spans import span
 from .stats import EngineStats
+
+# OA validates every step and defers no frees: there is never anything to
+# drain (see PagedServingEngine.reclaim_policy)
+_NO_DEFERRED_FREES = types.SimpleNamespace(drain_pending=lambda: False)
 
 
 class PagedServingEngine:
@@ -57,7 +63,6 @@ class PagedServingEngine:
                  pages_per_superblock: int = DEFAULT_PAGES_PER_SUPERBLOCK,
                  release_strategy: ReleaseStrategy = ReleaseStrategy.MADVISE,
                  release_quiescence: int | str | None = None,
-                 reclaim_policy: str | ReclamationPolicy | None = None,
                  min_mapped_superblocks: int = 1,
                  prefix_cache: bool = False,
                  prefix_cache_pages: int | None = None,
@@ -121,16 +126,6 @@ class PagedServingEngine:
                 # whole stack above sees denials/perturbations through the
                 # same Allocator surface it always talks to (core/chaos.py)
                 allocator = ChaosAllocator(allocator, chaos)
-            # reclamation policy (core/reclaim_policy.py): a name, a ready
-            # instance, or None (the RECLAIM_POLICY env var, default
-            # oa-validate).  wrap() interposes OUTSIDE chaos so the interval
-            # limbo defers the frees the fault schedule perturbs too.
-            policy = (reclaim_policy
-                      if isinstance(reclaim_policy, ReclamationPolicy)
-                      else make_policy(reclaim_policy))
-            self._reclaim_policy = policy
-            self.stats.record_policy(policy.name)
-            allocator = policy.wrap(allocator)
             self.stats.record_superblocks(allocator.view())
             self.kv_manager = KVCacheManager(
                 allocator,
@@ -163,7 +158,7 @@ class PagedServingEngine:
                 grant_retry_limit=grant_retry_limit, greedy=greedy,
                 speculative_k=speculative_k, drafter=drafter,
                 spec_probe_interval=spec_probe_interval,
-                reclaim_policy=policy, classes=classes,
+                classes=classes,
                 max_queue_depth=max_queue_depth,
                 victim_policy=victim_policy, ladder=ladder, clock=clock,
                 span=span)
@@ -187,12 +182,10 @@ class PagedServingEngine:
         while block and req.state == "rejected":
             self.scheduler.admit()
             if not self.scheduler.running:
-                if not self._reclaim_policy.drain_pending():
-                    raise MemoryError(
-                        "blocking submit: queue full and nothing running — "
-                        "the engine cannot make progress to drain it")
-            else:
-                self.step()
+                raise MemoryError(
+                    "blocking submit: queue full and nothing running — "
+                    "the engine cannot make progress to drain it")
+            self.step()
             self.scheduler.maintain()
             self.scheduler.requeue(req)
         return req
@@ -206,10 +199,8 @@ class PagedServingEngine:
         if not self.scheduler.running:
             return
         C, budget, drafts = self._plan()
-        do_validate = self.scheduler.plan_validate()
         res = self.runner.execute(self.kv_manager, chunk_size=C,
-                                  budget=budget, drafts=drafts,
-                                  do_validate=do_validate)
+                                  budget=budget, drafts=drafts)
         self.scheduler.absorb(res, C, budget, inject_preemption_of,
                               drafts=drafts)
 
@@ -230,10 +221,8 @@ class PagedServingEngine:
         if not self.scheduler.running:
             return None
         C, budget, drafts = self._plan()
-        do_validate = self.scheduler.plan_validate()
         return (self.runner.launch(self.kv_manager, chunk_size=C,
-                                   budget=budget, drafts=drafts,
-                                   do_validate=do_validate),
+                                   budget=budget, drafts=drafts),
                 C, budget, drafts)
 
     def collect_step(self, handle) -> None:
@@ -253,17 +242,9 @@ class PagedServingEngine:
             if not self.scheduler.running and not self.scheduler.queue:
                 break
             if not self.scheduler.running:  # queue blocked on memory
-                if self._reclaim_policy.drain_pending():
-                    continue  # deferred frees applied (no live reader —
-                    # every interval guarantee holds); retry admission
                 raise MemoryError("pool exhausted with empty running set")
             self.step()
             self.scheduler.maintain()
-        if not self.scheduler.running:
-            # drain complete: apply any frees still deferred (interval
-            # limbo, chaos delays) so the mirrors and release floors see
-            # the true free state — zero readers, so this is always sound
-            self._reclaim_policy.flush()
         if (self.scheduler.release_quiescence is not None
                 and not self.scheduler._adaptive_release):
             # drain: park the now-idle superblocks.  Adaptive mode skips
@@ -289,8 +270,6 @@ class PagedServingEngine:
             if not self.scheduler.running and not self.scheduler.queue:
                 break
             if not self.scheduler.running:  # queue blocked on memory
-                if self._reclaim_policy.drain_pending():
-                    continue
                 raise MemoryError("pool exhausted with empty running set")
             watch = list(self.scheduler.running)
             self.step()
@@ -303,8 +282,6 @@ class PagedServingEngine:
                     yield req, req.generated[seen:]
                     emitted[req.rid] = len(req.generated)
             self.scheduler.maintain()
-        if not self.scheduler.running:
-            self._reclaim_policy.flush()
         if (self.scheduler.release_quiescence is not None
                 and not self.scheduler._adaptive_release):
             self.shrink()
@@ -379,9 +356,11 @@ class PagedServingEngine:
         return self.kv_manager.allocator.release_strategy
 
     @property
-    def reclaim_policy(self) -> ReclamationPolicy:
-        """The live reclamation backend (core/reclaim_policy.py)."""
-        return self._reclaim_policy
+    def reclaim_policy(self):
+        """Read only by ``bench/engines/paged.py::Engine.unblock``: OA
+        defers no frees, so ``drain_pending()`` has nothing to apply and
+        returns False."""
+        return _NO_DEFERRED_FREES
 
     # internal-but-stable hooks the test suites drive directly
     _HOOKS = {

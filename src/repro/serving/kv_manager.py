@@ -21,15 +21,6 @@ can cause a zero-transition free ticks it exactly once per device batch
 that actually freed something — matching ``unshare_pages``' once-per-batch
 rule — so ``warnings_fired == pool.clock`` holds after any interleaving
 (tested per workload in the engine suites).
-
-Under the interval reclamation policy (``core/reclaim_policy.py``) the
-allocator this layer holds is an ``IntervalAllocator`` that DEFERS
-``free``/``unshare`` batches: the mirror still ticks here at call time
-while the device clock ticks when the batch matures, so the exactness
-contract is asserted at quiescent points (after the engine's drain-time
-``flush``) rather than mid-flight — each deferred batch corresponds 1:1 to
-one eventual device batch, which is what keeps the equality exact at every
-flushed point (``tests/test_reclaim_diff.py``).
 """
 
 from __future__ import annotations

@@ -227,7 +227,7 @@ def paged_decode_step(params, kv, block_tables, lengths, tokens, *, cfg,
 def fused_decode_step(params, kv, pool, block_tables, snapshot, lengths,
                       last_tok, active, prompt_buf, prompt_len, key,
                       temperature, chunk_budget=1, draft_toks=None,
-                      draft_lens=None, do_validate=None, *, cfg,
+                      draft_lens=None, *, cfg,
                       impl: str = "ref",
                       greedy: bool = True, chunk_size: int = 1,
                       speculative: bool = False, mesh=None):
@@ -251,12 +251,6 @@ def fused_decode_step(params, kv, pool, block_tables, snapshot, lengths,
                     optimistic draft tokens from the host-side drafter
       draft_lens    [B] int32 (``speculative`` only) — live drafts per row
                     (0..chunk_size−1); 0 = the row runs plain decode
-      do_validate   [] bool (traced; None = True) — run the phase-(6) OA
-                    validation pass this step.  The engine's reclamation
-                    policy (``core/reclaim_policy.py``) plans this per
-                    step: epoch-grace skips it on steady-state steps with
-                    no reclamation since the last validated step, interval
-                    always skips (its free→grant delay replaces it)
 
     Speculative decoding (``speculative=True``, greedy only): a DECODING
     row's chunk carries its last committed token at slot 0 and up to C−1
@@ -452,23 +446,12 @@ def fused_decode_step(params, kv, pool, block_tables, snapshot, lengths,
     # (6) fused OA validation: one pass over page_version for all C tokens.
     # Speculative rows advance by the ACCEPTED prefix only — the rejected
     # suffix's KV writes sit past the committed length in pages the row
-    # already holds, and the next append simply overwrites them.
-    # ``do_validate`` is a TRACED boolean (the reclamation policy's per-step
-    # verdict rides a resident device scalar, so skipping costs no recompile
-    # and no transfer); epoch-grace/interval policies elide the pass on
-    # steps where no reclamation can have invalidated a snapshot.  Grant
-    # starvation is checked unconditionally — it is an allocation outcome,
-    # not a reclamation hazard.
+    # already holds, and the next append simply overwrites them.  The pass
+    # runs on every step; grant starvation is a separate allocation
+    # outcome, folded in beside it.
     with jax.named_scope("step.validate"):
-        if do_validate is None:
-            do_val = jnp.asarray(True)
-        else:
-            do_val = jnp.asarray(do_validate, bool)
-        valid_oa = jax.lax.cond(
-            do_val,
-            lambda: pp._validate_and_commit_impl(pool, block_tables,
-                                                 snapshot)[0],
-            lambda: jnp.ones((B,), bool))
+        valid_oa = pp._validate_and_commit_impl(pool, block_tables,
+                                                snapshot)[0]
         valid = valid_oa & active & grant_ok
         adv = jnp.where(valid, commit_n, 0).astype(jnp.int32)
         lengths = lengths + adv

@@ -140,12 +140,6 @@ class DataParallelEngine:
         chaos = kw.get("chaos")
         if chaos is not None:
             kw["chaos"] = dataclasses.replace(chaos, seed=chaos.seed + i)
-        policy = kw.get("reclaim_policy")
-        if policy is not None and not isinstance(policy, str):
-            # a ReclamationPolicy INSTANCE is stateful and wraps exactly one
-            # allocator — replicas (and revivals) must each build their own,
-            # so only the NAME fans out across the fleet
-            kw["reclaim_policy"] = policy.name
         return kw
 
     # -- routing -------------------------------------------------------------

@@ -72,27 +72,19 @@ class ModelRunner:
         # clipped to 1 anyway: pure-decode steps must not pay a per-step
         # host->device upload for a value that cannot matter
         self._budget_one = jnp.asarray(1, jnp.int32)
-        # resident device booleans for the reclamation policy's per-step
-        # validation verdict: a TRACED operand of the fused step (selecting
-        # a lax.cond branch at runtime, same executable either way), kept
-        # resident so skipping validation never costs a per-step upload
-        self._val_true = jnp.asarray(True)
-        self._val_false = jnp.asarray(False)
         if mesh is not None:
             # every array entering the fused jit must live on the SAME mesh
             # (committed single-device scalars beside mesh-committed state
             # is a placement error) — pin the resident scalars replicated
             rep = jax.sharding.NamedSharding(
                 mesh, jax.sharding.PartitionSpec())
-            (self._temperature, self._base_key, self._budget_one,
-             self._val_true, self._val_false) = jax.device_put(
-                (self._temperature, self._base_key, self._budget_one,
-                 self._val_true, self._val_false), rep)
+            (self._temperature, self._base_key,
+             self._budget_one) = jax.device_put(
+                (self._temperature, self._base_key, self._budget_one), rep)
         self._step_idx = 0
 
     def launch(self, kvm: KVCacheManager, *, chunk_size: int = 1,
-               budget: int = 1, drafts: dict | None = None,
-               do_validate: bool = True):
+               budget: int = 1, drafts: dict | None = None):
         """Dispatch ONE fused step and immediately install the (possibly
         still in-flight — jax arrays are futures) device state back into
         the manager.  Returns the pending per-slot outputs for
@@ -104,12 +96,7 @@ class ModelRunner:
         SPECULATIVE executable: the plan is packed into dense
         [B, chunk_size−1] / [B] arrays and rides the dispatch as a
         host→device upload — an upload, never a download, so the
-        one-``device_get``-per-step invariant is untouched.
-
-        ``do_validate`` is the reclamation policy's verdict for THIS step
-        (``Scheduler.plan_validate``): False elides the fused OA
-        validation pass via a resident device boolean — no recompile, no
-        transfer, same executable."""
+        one-``device_get``-per-step invariant is untouched."""
         with span(RUNNER_LAUNCH):
             self._step_idx += 1
             # greedy decode never consumes the key: skip the fold_in
@@ -136,7 +123,6 @@ class ModelRunner:
                 (self._budget_one if chunk_size == 1
                  else jnp.asarray(budget, jnp.int32)),
                 draft_args[0], draft_args[1],
-                self._val_true if do_validate else self._val_false,
                 cfg=self.cfg, impl=self.attn_impl, greedy=self.greedy,
                 chunk_size=chunk_size, speculative=speculative, mesh=self.mesh)
             kvm.install_state(DeviceStepState(
@@ -151,10 +137,8 @@ class ModelRunner:
             return StepResult(*jax.device_get(pending))
 
     def execute(self, kvm: KVCacheManager, *, chunk_size: int = 1,
-                budget: int = 1, drafts: dict | None = None,
-                do_validate: bool = True) -> StepResult:
+                budget: int = 1, drafts: dict | None = None) -> StepResult:
         """One full step: launch the fused dispatch, then collect its single
         host transfer (the single-replica path)."""
         return self.collect(self.launch(
-            kvm, chunk_size=chunk_size, budget=budget, drafts=drafts,
-            do_validate=do_validate))
+            kvm, chunk_size=chunk_size, budget=budget, drafts=drafts))

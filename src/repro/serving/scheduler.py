@@ -27,7 +27,6 @@ import time
 
 import numpy as np
 
-from repro.core.reclaim_policy import ReclamationPolicy, make_policy
 from repro.core.vm import superblock_floor
 from .draft import NGramDrafter
 from .kv_manager import KVCacheManager
@@ -345,7 +344,6 @@ class Scheduler:
                  grant_retry_limit: int = 8, greedy: bool = True,
                  speculative_k: int = 0, drafter=None,
                  spec_probe_interval: int = 16,
-                 reclaim_policy: ReclamationPolicy | None = None,
                  classes: dict | None = None,
                  max_queue_depth: int | None = None,
                  victim_policy="youngest",
@@ -404,12 +402,6 @@ class Scheduler:
         self._gap_ewma: float | None = None
         self._adaptive_floor = 2  # lower clamp once a cadence is learned
         self._adaptive_bootstrap = 16  # threshold before ANY gap is observed
-        # reclamation policy: plans whether each fused step runs the OA
-        # validation pass, and (interval) defers frees behind the allocator
-        self.policy = (reclaim_policy if reclaim_policy is not None
-                       else make_policy())
-        self._step_validates = True  # absorb()'s view of the LAST plan
-        self._planned_clock = 0  # clock mirror at the last plan
         self.min_mapped_superblocks = max(1, min_mapped_superblocks)
         # denied admission grants get this many PLAIN retries before the
         # escalation chain (remap -> evict -> preempt) — a transient denial
@@ -706,12 +698,6 @@ class Scheduler:
                     continue
                 if self.prefix_cache and self.index.evict(1) > 0:
                     continue
-                if self.policy.pending_frees():
-                    # deferred frees (interval limbo) mature within the
-                    # lag; a preemption now would only add to the limbo
-                    # without making a single page grantable — wait
-                    self._unshare_admission(shared)
-                    return False
                 victim = self.pick_victim(exclude=req)
                 if victim is None:
                     self._unshare_admission(shared)
@@ -837,8 +823,6 @@ class Scheduler:
             return True
         if not self.running:
             return False
-        if self.policy.pending_frees():
-            return False  # limbo frees mature within the lag; retry then
         self.preempt(self.pick_victim())
         return True
 
@@ -875,20 +859,6 @@ class Scheduler:
             self._spec_probe = 0
             return 1
         return 0
-
-    @_spanned(SCHED_PLAN)
-    def plan_validate(self) -> bool:
-        """Ask the reclamation policy whether THIS step's fused dispatch
-        must run the OA validation pass (host mirrors only — the clock
-        mirror is ``stats.warnings_fired``).  Remembers the verdict and the
-        mirror value for :meth:`absorb`'s bookkeeping: a mirror tick that
-        lands DURING the step (e.g. a COW zero-transition discovered at
-        absorb) moves the mirror past the planned value, so the next plan
-        validates again — conservative by construction."""
-        self._planned_clock = self.stats.warnings_fired
-        self._step_validates = self.policy.needs_validation(
-            self._planned_clock)
-        return self._step_validates
 
     @_spanned(SCHED_PLAN)
     def plan_chunk(self) -> tuple[int, int, dict | None]:
@@ -1025,15 +995,6 @@ class Scheduler:
             if req.state != "running":
                 continue  # preempted mid-flight; its row is dead anyway
             i = req.slot
-            if (not self.policy.detects_stale_readers
-                    and req.externally_reclaimed):
-                # this policy runs no device validation pass (interval): an
-                # external reclaim is outside its free→grant discipline, so
-                # the stale reader is detected HERE, host-side — same
-                # restart surface as an OA validation failure
-                self.stats.record_restart()
-                self.preempt(req)
-                continue
             if not valid_np[i]:
                 if grant_np[i] < 0:
                     starved.append(req)  # stays running; retry after eviction
@@ -1099,13 +1060,6 @@ class Scheduler:
                 else:
                     self.spec_k_cap //= 2
             self.stats.record_spec_step(self.spec_k_cap)
-        # reclamation-policy bookkeeping: count the pass/skip, remember the
-        # epoch a validated step was planned at, advance the interval (the
-        # interval policy's limbo frees mature here, once per step)
-        self.stats.record_validation(self._step_validates)
-        if self._step_validates:
-            self.policy.on_validated(self._planned_clock)
-        self.policy.on_step()
         self.stats.record_step(chunked=C > 1 and self._planned_prefill)
         self._update_speed_model(committed_this_step)
         pool_pressure = (self.distinct_pages_in_use()
@@ -1210,12 +1164,7 @@ class Scheduler:
         again — shared pages counted once, plus one page per row still
         sharing its write-position (tail) page, whose first divergent write
         grants a COW copy (omit that and a floor-exact shrink ping-pongs
-        with the growth path's remap).  With zero running rows, deferred
-        frees (interval limbo) are applied first — no reader is live, so
-        every interval guarantee is trivially satisfied and the release
-        arithmetic sees the true free state."""
-        if not self.running and self.policy.pending_frees():
-            self.policy.drain_pending()
+        with the growth path's remap)."""
         # measure the maintain-tick cadence on the real clock so admit-gap
         # seconds can be converted into tick units (see _note_arrival);
         # EWMA, outlier-clipped like the speed model

@@ -18,7 +18,7 @@ from __future__ import annotations
 import jax
 
 SCHED_ADMIT = "sched.admit"  # Scheduler.admit
-SCHED_PLAN = "sched.plan"  # Scheduler.plan_chunk, Scheduler.plan_validate
+SCHED_PLAN = "sched.plan"  # Scheduler.plan_chunk
 SCHED_ABSORB = "sched.absorb"  # Scheduler.absorb
 SCHED_MAINTAIN = "sched.maintain"  # Scheduler.maintain
 RUNNER_LAUNCH = "runner.launch"  # packing, budget upload, fused dispatch

@@ -157,11 +157,6 @@ class EngineStats:
     requests_migrated: int = 0  # requeued onto this replica from a dead one
     replica_failures: int = 0  # this replica died or stalled mid-run
     replica_revivals: int = 0  # fresh engines re-admitted after a failure
-    # reclamation-policy accounting (core/reclaim_policy.py): which backend
-    # is live and how many fused steps ran vs elided the OA validation pass
-    reclaim_policy: str = "oa-validate"
-    validation_passes: int = 0
-    validation_skipped: int = 0
     # backpressure gauges (latest observation, not counters): pool pressure
     # is distinct-live-pages over mapped capacity, aimd_ratio the chunk
     # budget cap over its configured chunk (1.0 = no backoff in force)
@@ -253,19 +248,6 @@ class EngineStats:
         AIMD cap in force (gauge — latest observation wins)."""
         self.spec_steps += 1
         self.draft_k = draft_k
-
-    def record_validation(self, ran: bool) -> None:
-        """One fused step retired; it either ran the OA validation pass
-        (``ran``) or the reclamation policy elided it."""
-        if ran:
-            self.validation_passes += 1
-        else:
-            self.validation_skipped += 1
-
-    def record_policy(self, name: str) -> None:
-        """Pin which reclamation backend this engine runs (a label, set
-        once at engine build)."""
-        self.reclaim_policy = name
 
     # -- reclamation (the OA warning channel) -------------------------------
 
@@ -410,8 +392,6 @@ def aggregate_stats(parts: list[EngineStats],
         total.spec_steps += s.spec_steps
         # draft_k is a gauge: report the most aggressive live cap
         total.draft_k = max(total.draft_k, s.draft_k)
-        total.validation_passes += s.validation_passes
-        total.validation_skipped += s.validation_skipped
         total.grant_denials += s.grant_denials
         total.grant_retries += s.grant_retries
         total.requests_shed += s.requests_shed
@@ -451,7 +431,6 @@ def aggregate_stats(parts: list[EngineStats],
         total.accept_rate = total.tokens_accepted / total.tokens_drafted
     if parts:
         total.release_strategy = parts[0].release_strategy
-        total.reclaim_policy = parts[0].reclaim_policy
     wall = (max((s.wall_seconds for s in parts), default=0.0)
             if wall_seconds is None else wall_seconds)
     total.record_wall(wall)

@@ -112,12 +112,9 @@ def test_grant_denials_are_retried_to_completion(params):
 def test_spurious_validation_failures_restart_and_recover(params):
     """Perturbed snapshots make rows fail OA validation exactly as if a
     reclaimer raced them: the engine restarts those requests and still
-    produces token-exact output.  Pinned to oa-validate — this is the
-    device validation surface itself, which skipping policies (interval;
-    epoch-grace on clean epochs) deliberately do not exercise."""
+    produces token-exact output."""
     base = _oracle(params, PROMPTS[:4], 5)
-    eng = _engine(params, chaos=ChaosConfig(seed=5, spurious_invalid_p=0.4),
-                  reclaim_policy="oa-validate")
+    eng = _engine(params, chaos=ChaosConfig(seed=5, spurious_invalid_p=0.4))
     rs = [eng.submit(p, 5) for p in PROMPTS[:4]]
     eng.run()
     assert all(r.state == "finished" for r in rs)

@@ -14,6 +14,7 @@ import numpy as np
 from hypothesis import HealthCheck, given, settings
 
 from repro.core import pagepool as pp
+from repro.core.vm import ReleaseStrategy
 
 SETTINGS = dict(max_examples=25, deadline=None,
                 suppress_health_check=[HealthCheck.too_slow])
@@ -60,6 +61,21 @@ def test_stale_read_detected_after_reuse():
     pool, again, _ = pp.alloc_pages(pool, 2)  # same physical pages (LIFO)
     assert set(np.asarray(again).tolist()) == set(np.asarray(pages).tolist())
     assert not bool(pp.validate_read(pool, pages, snap))
+
+
+def test_oa_stale_snapshot_detected_after_free_realloc():
+    """The same rule through the stateful allocator: free bumps the
+    version, so a reader's pre-free snapshot can never match a re-granted
+    page."""
+    pool = pp.DevicePagePool(16, 4, ReleaseStrategy.MADVISE)
+    ids, ok = pool.alloc(2)
+    assert ok
+    before = np.asarray(pool.snapshot(ids))
+    pool.free(ids)
+    again, ok = pool.alloc(2)
+    assert ok and set(again) == set(ids)  # LIFO free list re-grants them
+    after = np.asarray(pool.snapshot(ids))
+    assert (after != before).all(), "free->realloc must be snapshot-visible"
 
 
 @given(st.data())

@@ -92,7 +92,7 @@ def test_traced_round_records_nested_spans(params, tmp_path):
     order = [by[n] for n in (spans.RUNNER_LAUNCH, spans.RUNNER_COLLECT,
                              spans.SCHED_ABSORB, spans.SCHED_MAINTAIN)]
     plans = [g for g in got if g[0] == spans.SCHED_PLAN]
-    assert len(plans) == 2  # plan_chunk, plan_validate
+    assert len(plans) == 1  # plan_chunk
     seq = plans + order
     assert all(a[2] <= b[1] for a, b in zip(seq, seq[1:]))
 
@@ -118,7 +118,6 @@ def _drive(sched, kvm, n_req=3) -> list:
         if not sched.running and not sched.queue:
             break
         C, budget, _ = sched.plan_chunk()
-        sched.plan_validate()
         sched.absorb(runner.execute(kvm), C, budget)
         sched.maintain()
     return reqs
@@ -166,7 +165,7 @@ def test_fused_step_hlo_carries_phase_and_pool_scopes(params):
     hlo = fused_decode_step.lower(
         params, st.kv, st.pool, st.block_tables, st.snapshot, st.lengths,
         st.last_tok, st.active, st.prompt_buf, st.prompt_len, r._base_key,
-        r._temperature, r._budget_one, None, None, r._val_true, cfg=CFG,
+        r._temperature, r._budget_one, None, None, cfg=CFG,
         impl=r.attn_impl, chunk_size=4).compile().as_text()
     parts = {p for name in re.findall(r'op_name="([^"]*)"', hlo)
              for p in name.split("/")}

@@ -161,33 +161,6 @@ def test_speculative_step_is_single_transfer(monkeypatch, params):
     assert eng.stats.tokens_accepted > 0, "window must contain accepted drafts"
 
 
-@pytest.mark.parametrize("policy", ["oa-validate", "epoch-grace", "interval"])
-def test_steady_state_single_transfer_per_reclaim_policy(monkeypatch, params,
-                                                         policy):
-    """Swapping the reclamation backend must not cost the hot path anything:
-    the policy's per-step validation verdict rides a RESIDENT device boolean
-    (selecting a lax.cond branch — same executable), the interval limbo
-    defers frees without a single device read, and the epoch check is pure
-    host-mirror arithmetic.  One ``device_get`` per steady step, for every
-    policy."""
-    eng = PagedServingEngine(CFG, params, num_pages=32, page_size=4,
-                             max_batch=2, max_pages_per_seq=8,
-                             reclaim_policy=policy)
-    eng.submit(list(range(1, 5)), 20)
-    eng.submit(list(range(2, 6)), 20)
-    eng._admit()
-    for _ in range(3):  # compile + settle
-        eng.step()
-    counter = _TransferCounter()
-    _instrument(monkeypatch, counter)
-    nsteps = 6
-    for _ in range(nsteps):
-        eng.step()
-    assert counter.count <= nsteps, (
-        f"{policy}: {counter.count} host transfers across {nsteps} "
-        f"steady-state steps (sync-free hot path allows at most 1 per step)")
-
-
 def test_steady_state_single_transfer_with_ladder_engaged(monkeypatch,
                                                           params):
     """Overload response must not cost the hot path anything: with the

@@ -216,7 +216,7 @@ hlo = fused_decode_step.lower(
     e2.params, st2.kv, st2.pool, st2.block_tables, st2.snapshot,
     st2.lengths, st2.last_tok, st2.active, st2.prompt_buf, st2.prompt_len,
     r2._base_key, r2._temperature, r2._budget_one, None, None,
-    r2._val_true, cfg=CFG, impl=r2.attn_impl, chunk_size=4,
+    cfg=CFG, impl=r2.attn_impl, chunk_size=4,
     mesh=e2.mesh).compile().as_text()
 out = {
     "tokens_equal": t1 == t2,

@@ -196,7 +196,7 @@ def test_fused_step_keeps_arena_whole(one_chip, chunk):
         sds((BATCH,), i32), sds((BATCH,), bool),
         sds((BATCH, PROMPT_CAP), i32), sds((BATCH,), i32),
         sds((2,), jnp.uint32), sds((), jnp.float32), sds((), i32), None,
-        None, sds((), bool), cfg=cfg, impl="pallas", greedy=True,
+        None, cfg=cfg, impl="pallas", greedy=True,
         chunk_size=chunk).compile()
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
@@ -249,7 +249,7 @@ def test_nemotron_step_fits_and_keeps_arena_whole(one_chip, chunk):
         sds((B, M), jnp.uint32), sds((B,), i32), sds((B,), i32),
         sds((B,), bool), sds((B, NEMOTRON_PROMPT), i32), sds((B,), i32),
         sds((2,), jnp.uint32), sds((), jnp.float32), sds((), i32), None,
-        None, sds((), bool), cfg=cfg, impl="pallas", greedy=True,
+        None, cfg=cfg, impl="pallas", greedy=True,
         chunk_size=chunk).compile()
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
